@@ -27,8 +27,8 @@ benchmarks/roofline.py); `derived` carries the table's headline quantity
   bench_online_update        closed-loop updates/s (incremental last-layer
                              solve vs jitted mini-refit) + NetworkEstimator
                              per-offload overhead
-  bench_fleet_scale          sharded data-plane scoring streams/s at 1 vs N
-                             forced host-device shards (subprocess per view)
+  bench_fleet_scale          sharded data-plane scoring streams/s on a 1-device
+                             mesh vs a mesh over every visible device
   bench_mobility_handover    motion-scan rollout throughput + handover-aware
                              vs static-pin effective accuracy at equal budget
   bench_iou                  iou_matrix ref vs Pallas side by side (+ratio)
@@ -44,6 +44,8 @@ registered in the full/smoke selection — CI runs it so a new bench can't
 silently drop out of the smoke allowlist.  Every full run also writes
 ``artifacts/BENCH_<rev>.json`` (per-bench median ms + shapes) so the perf
 trajectory is tracked across commits; CI uploads it as an artifact.
+JAX's persistent compile cache goes to ``JAX_COMPILATION_CACHE_DIR`` when
+it is set, else to ``.jax_cache/`` in the checkout.
 """
 from __future__ import annotations
 
@@ -56,7 +58,8 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-ART = os.path.join(os.path.dirname(__file__), "../artifacts")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ART = os.path.join(ROOT, "artifacts")
 ROWS: List[str] = []
 BENCHES: List[Dict] = []
 
@@ -640,64 +643,32 @@ def bench_online_update(n: int = 512, block: int = 8) -> None:
     )
 
 
-_FLEET_SCALE_CHILD = """
-import sys, time
-import numpy as np
-
-n_streams = int(sys.argv[1])
-import jax
-from repro.api import MLPRewardModel, OffloadEngine
-from repro.core import EstimatorConfig
-from repro.fleet import FleetPlane
-
-rng = np.random.default_rng(0)
-x = rng.normal(0, 1, (1024, 387)).astype(np.float32)
-eng = OffloadEngine(
-    reward_model=MLPRewardModel(config=EstimatorConfig(hidden=(128,), epochs=2))
-)
-eng.fit(features=x, rewards=rng.normal(0, 1, 1024))
-plane = FleetPlane()
-feats = rng.normal(0, 1, (n_streams, 387)).astype(np.float32)
-ref = np.asarray(eng.score(features=feats))
-out = np.asarray(plane.score(eng, feats))  # also warms the sharded path
-assert np.array_equal(ref, out), "sharded scoring diverged"
-samples = []
-for _ in range(5):
-    t0 = time.perf_counter()
-    np.asarray(plane.score(eng, feats))
-    samples.append(time.perf_counter() - t0)
-print("RESULT", len(jax.devices()), float(np.median(samples)) * 1e6)
-"""
-
-
 def bench_fleet_scale(n_streams: int = 2048) -> None:
-    """Sharded fleet-plane scoring throughput at 1 vs 4 forced host-device
-    shards.  ``XLA_FLAGS`` must be set before jax initializes, so each
-    device view runs in its own subprocess (compile excluded — the child
-    reports a warmed median); the child also re-checks bit-identity against
-    the single-device engine path.  On a single-core host the scaling is
-    honestly flat; on a multi-core host the fan-out shows."""
-    import sys
+    """Sharded fleet-plane scoring throughput on a 1-device mesh and on a
+    mesh over every visible device, both built in this process (one
+    process holds the chips).  On a CPU host, run the whole command under
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` to get four
+    devices; with one visible device only the 1-device row is emitted.
+    Each mesh re-checks bit-identity against the single-device engine path
+    (compile excluded — the median is warmed)."""
+    from repro.fleet import FleetPlane
+    from repro.launch.mesh import make_fleet_mesh
 
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "../src"))
-    for shards in (1, 4):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={shards}"
-        env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        proc = subprocess.run(
-            [sys.executable, "-c", _FLEET_SCALE_CHILD, str(n_streams)],
-            capture_output=True, text=True, timeout=540, env=env,
-        )
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"fleet_scale child (shards={shards}) failed:\n{proc.stderr}"
-            )
-        line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][-1]
-        _, n_dev, us = line.split()
-        us = float(us)
+    eng, _ = _smoke_engine()
+    feats = np.random.default_rng(0).normal(0, 1, (n_streams, 387)).astype(np.float32)
+    ref = np.asarray(eng.score(features=feats))
+    meshes = [make_fleet_mesh(1)]
+    if make_fleet_mesh().devices.size > 1:
+        meshes.append(make_fleet_mesh())
+    for mesh in meshes:
+        plane = FleetPlane(mesh)
+        out = plane.score(eng, feats)  # also warms the sharded path
+        assert np.array_equal(ref, out), "sharded scoring diverged"
+        us = _timeit(lambda: plane.score(eng, feats), n=5, warmup=0)
+        shards = plane.n_devices
         emit(
             f"fleet_scale_shards{shards}", us / n_streams,
-            f"streams_per_s={n_streams / (us / 1e6):.0f};devices={n_dev}",
+            f"streams_per_s={n_streams / (us / 1e6):.0f};devices={shards}",
             shape={"streams": n_streams, "features": 387, "shards": shards},
         )
 
@@ -965,6 +936,12 @@ def main(argv=None) -> None:
         ]
         if not selected:
             ap.error(f"--only {args.only!r} matches no bench")
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        import jax
+
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(ROOT, ".jax_cache")
+        )
     print("name,us_per_call,derived")
     os.makedirs(ART, exist_ok=True)
     for _, fn in selected:

@@ -126,6 +126,16 @@ class _BoxBatch:
             kwargs[f.name] = np.concatenate([a, pad])
         return type(self)(**kwargs)
 
+    def slice_images(self, start: int, stop: int):
+        """Images ``[start, stop)`` as a batch of the same type (views of
+        this batch's arrays, no copy)."""
+        return type(self)(
+            **{
+                f.name: getattr(self, f.name)[start:stop]
+                for f in dataclasses.fields(self)
+            }
+        )
+
     def __len__(self) -> int:
         return self.boxes.shape[0]
 

@@ -39,7 +39,6 @@ from typing import Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.features import _features_kernel, extract_features_batch
@@ -96,12 +95,12 @@ class FleetPlane:
 
     def _shard1d(self, fn, n_in: int, n_out: int):
         """``shard_map`` ``fn`` with every input/output sharded on axis 0."""
-        return shard_map(
+        return jax.shard_map(
             fn,
             mesh=self.mesh,
             in_specs=(P(self.axis),) * n_in,
             out_specs=(P(self.axis),) * n_out if n_out > 1 else P(self.axis),
-            check_rep=False,
+            check_vma=False,
         )
 
     # ------------------------------------------------------------- scoring

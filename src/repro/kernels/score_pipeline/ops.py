@@ -23,11 +23,9 @@ MLP — runs as ONE jitted dispatch:
 (TPU/GPU), ``"lax"`` on CPU (the interpreter would be slower than the jit
 — the same reasoning as ``repro.kernels.dispatch.resolve_path``).
 
-Buffer donation: on accelerator backends the lax path donates the four
-detection arrays (they are consumed by the dispatch — pending blocks are
-dead after the policy boundary), letting XLA reuse their buffers for the
-feature stage.  CPU ignores donation, so the donating jit is only built
-off-CPU.
+No path donates its inputs: a caller may score the same device-resident
+block twice (``decide`` after ``score_device``, or a plain reference next
+to the fused path), and a donated block would be deleted by the first call.
 """
 from __future__ import annotations
 
@@ -40,7 +38,11 @@ import numpy as np
 
 from repro.core.features import feature_dim
 from repro.detection.batch import DetectionsBatch
-from repro.kernels.score_pipeline.kernel import score_pipeline_pallas
+from repro.kernels.score_pipeline.kernel import (
+    N_BOX_STATS,
+    N_GLOBAL_STATS,
+    score_pipeline_pallas,
+)
 from repro.kernels.score_pipeline.ref import score_pipeline_ref
 from repro.obs.jit_stats import register_jit
 
@@ -90,19 +92,10 @@ def pipeline_params(model) -> Dict[str, jnp.ndarray]:
     }
 
 
-_LAX_JITS: Dict[bool, "jax.stages.Wrapped"] = {}
-
-
-def _lax_jit(donate: bool):
-    if donate not in _LAX_JITS:
-        kwargs = dict(static_argnames=("num_classes", "top_k"))
-        if donate:
-            kwargs["donate_argnums"] = (0, 1, 2, 3)
-        _LAX_JITS[donate] = register_jit(
-            "score_pipeline.lax_donate" if donate else "score_pipeline.lax",
-            jax.jit(score_pipeline_ref, **kwargs),
-        )
-    return _LAX_JITS[donate]
+_score_pipeline_lax = register_jit(
+    "score_pipeline.lax",
+    jax.jit(score_pipeline_ref, static_argnames=("num_classes", "top_k")),
+)
 
 
 def _ceil_to(n: int, multiple: int) -> int:
@@ -133,21 +126,39 @@ def _score_pipeline_pallas(
     bx = jnp.take_along_axis(boxes, order[:, :, None], axis=1) / image_size
 
     B = s.shape[0]
-    F, H = w1.shape
-    Bp, Fp, Hp = _ceil_to(B, tile_b), _ceil_to(F, 128), _ceil_to(H, 128)
-    s_p = jnp.zeros((Bp, top_k), jnp.float32).at[:B].set(s)
-    bx_p = jnp.zeros((Bp, top_k, 4), jnp.float32).at[:B].set(bx)
-    cls_p = jnp.zeros((Bp, top_k), jnp.int32).at[:B].set(cls)
-    m_p = jnp.zeros((Bp, top_k), jnp.float32).at[:B].set(m)
-    w1_p = jnp.zeros((Fp, Hp), jnp.float32).at[:F, :H].set(w1)
+    H = w1.shape[1]
+    T = N_BOX_STATS + num_classes  # per-box feature planes
+    G = N_GLOBAL_STATS + num_classes  # global feature columns
+    Bp, Kp = _ceil_to(B, tile_b), _ceil_to(top_k, 8)
+    Hp, Gp = _ceil_to(H, 128), _ceil_to(G, 128)
+    s_p = jnp.zeros((Bp, Kp), jnp.float32).at[:B, :top_k].set(s)
+    m_p = jnp.zeros((Bp, Kp), jnp.float32).at[:B, :top_k].set(m)
+    cls_p = jnp.zeros((Bp, Kp), jnp.int32).at[:B, :top_k].set(cls)
+    bx_p = jnp.zeros((4, Bp, Kp), jnp.float32).at[:, :B, :top_k].set(
+        jnp.moveaxis(bx, 2, 0)
+    )
+    # feature f = k * T + t of the box block feeds plane t at slot k
+    nb = top_k * T
+    w1_box = jnp.zeros((T, Kp, Hp), jnp.float32).at[:, :top_k, :H].set(
+        w1[:nb].reshape(top_k, T, H).transpose(1, 0, 2)
+    )
+    mu_box = jnp.zeros((T, Kp), jnp.float32).at[:, :top_k].set(
+        mu[:nb].reshape(top_k, T).T
+    )
+    sig_box = jnp.ones((T, Kp), jnp.float32).at[:, :top_k].set(
+        sigma[:nb].reshape(top_k, T).T
+    )
+    w1_glob = jnp.zeros((Gp, Hp), jnp.float32).at[:G, :H].set(w1[nb:])
+    mu_glob = jnp.zeros((1, Gp), jnp.float32).at[0, :G].set(mu[nb:])
+    sig_glob = jnp.ones((1, Gp), jnp.float32).at[0, :G].set(sigma[nb:])
     b1_p = jnp.zeros((1, Hp), jnp.float32).at[0, :H].set(b1)
     w2_p = jnp.zeros((Hp, 128), jnp.float32).at[:H, 0].set(w2)
     b2_p = jnp.zeros((1, 128), jnp.float32).at[0, 0].set(b2)
-    mu_p = jnp.zeros((1, Fp), jnp.float32).at[0, :F].set(mu)
-    sig_p = jnp.ones((1, Fp), jnp.float32).at[0, :F].set(sigma)
     out = score_pipeline_pallas(
-        s_p, bx_p, cls_p, m_p, w1_p, b1_p, w2_p, b2_p, mu_p, sig_p,
-        num_classes=num_classes, f_dim=F, tile_b=tile_b, interpret=interpret,
+        s_p, m_p, cls_p, bx_p, w1_box, mu_box, sig_box,
+        w1_glob, mu_glob, sig_glob, b1_p, w2_p, b2_p,
+        num_classes=num_classes, top_k=top_k, tile_b=tile_b,
+        interpret=interpret,
     )
     return out[:B, 0]
 
@@ -191,8 +202,7 @@ def score_pipeline(
     resolved = resolve_pipeline_path(path)
     p = params
     if resolved == "lax":
-        fn = _lax_jit(donate=jax.default_backend() != "cpu")
-        return fn(
+        return _score_pipeline_lax(
             boxes, scores, classes, mask,
             p["w1"], p["b1"], p["w2"], p["b2"], p["mu"], p["sigma"],
             np.float32(image_size), int(num_classes), int(top_k),

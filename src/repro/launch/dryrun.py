@@ -22,13 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import ARCH_IDS  # noqa: E402
 from repro.launch.input_specs import SHAPES, input_specs  # noqa: E402
-from repro.launch.mesh import (  # noqa: E402
-    HBM_BW,
-    ICI_BW,
-    PEAK_FLOPS_BF16,
-    logical_axes,
-    make_production_mesh,
-)
+from repro.launch.mesh import logical_axes, make_production_mesh  # noqa: E402
 from repro.launch.meshctx import bind_mesh  # noqa: E402
 from repro.launch.sharding import (  # noqa: E402
     batch_shardings,
@@ -45,6 +39,12 @@ from repro.launch.steps import (  # noqa: E402
 from repro.models.lm import abstract_params, init_cache  # noqa: E402
 
 ARTIFACTS = os.path.join(os.path.dirname(__file__), "../../../artifacts/dryrun")
+
+# Per-chip roofline terms of the dry-run estimate, TPU v5e (Google Cloud
+# documentation, "TPU v5e"); a planning figure, never a measurement.
+PEAK_FLOPS_BF16 = 197e12  # FLOP/s
+HBM_BW = 819e9  # bytes/s
+ICI_BW = 50e9  # bytes/s per link
 
 COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")

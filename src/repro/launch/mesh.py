@@ -38,15 +38,17 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_fleet_mesh(n_shards: Optional[int] = None, *, axis: str = FLEET_AXIS) -> Mesh:
-    """A 1-D city-scale *serving* mesh: ``n_shards`` devices along one
-    ``"shard"`` axis, streams sharded over it (see ``repro.fleet.plane``).
+    """A 1-D city-scale *serving* mesh: the first ``n_shards`` of
+    ``jax.devices()`` along one ``"shard"`` axis, streams sharded over it
+    (see ``repro.fleet.plane``).
 
-    Built for CPU host-device fan-out: under
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` every host
-    thread pool slice becomes a shard.  ``n_shards=None`` takes every
-    visible device; asking for more shards than devices clamps to the
-    available count (a 1-device CI run gets a 1-shard mesh and the sharded
-    data plane degrades to the single-device path)."""
+    The devices are whatever the backend exposes: the chips of a TPU host
+    (four on a v5e 2x2 host), or CPU devices forced with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+    ``n_shards=None`` takes every visible device; asking for more shards
+    than devices clamps to the available count, so a 1-device run gets a
+    1-shard mesh and the sharded data plane degrades to the single-device
+    path."""
     devices = jax.devices()
     n = len(devices) if n_shards is None else int(n_shards)
     if n < 1:
@@ -63,9 +65,3 @@ def logical_axes(*, multi_pod: bool = False) -> Dict[str, AxisVal]:
         "expert": "model",  # expert-parallel over the model axis
         "data_only": "data",
     }
-
-
-# Hardware constants (per chip) for the roofline terms — TPU v5e class.
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s per link

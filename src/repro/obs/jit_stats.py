@@ -31,7 +31,11 @@ _CALLS: Dict[str, int] = {}
 
 def register_jit(site: str, fn: Any) -> Any:
     """Register a jitted callable under ``site`` and return it unchanged
-    (safe to wrap the jit-construction expression in place)."""
+    (safe to wrap the jit-construction expression in place).  Anything
+    without a jit cache is refused here, so a snapshot never has to guess
+    a site's retrace count."""
+    if not callable(getattr(fn, "_cache_size", None)):
+        raise TypeError(f"register_jit({site!r}) needs a jax.jit callable, got {fn!r}")
     _SITES[str(site)] = fn
     return fn
 
@@ -42,22 +46,13 @@ def count_call(site: str, n: int = 1) -> None:
     _CALLS[site] = _CALLS.get(site, 0) + n
 
 
-def _cache_size(fn: Any) -> int:
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        # not a jax.jit (reference-path plain function) or a jax version
-        # without the probe: report 0 rather than breaking observability
-        return 0
-
-
 def snapshot() -> Dict[str, Tuple[int, int]]:
     """``{site: (traces, calls)}`` — ``traces`` is the jit cache size
     (distinct compiled specializations so far), ``calls`` the manual
     counter (0 unless the site uses :func:`count_call`)."""
     out: Dict[str, Tuple[int, int]] = {}
     for site, fn in _SITES.items():
-        out[site] = (_cache_size(fn), _CALLS.get(site, 0))
+        out[site] = (int(fn._cache_size()), _CALLS.get(site, 0))
     for site, n in _CALLS.items():
         if site not in _SITES:
             out[site] = (0, n)

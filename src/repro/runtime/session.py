@@ -31,6 +31,7 @@ _MIN_BUFFER_ROWS = 64
 
 from repro.api.engine import OffloadEngine
 from repro.api.policies import make_policy, policy_context_params
+from repro.detection.batch import DetectionsBatch
 from repro.obs.metrics import Counter, Gauge, Histogram, DEFAULT_TIME_BUCKETS
 
 
@@ -426,33 +427,35 @@ class OffloadSession:
         ``flush=False`` a trailing partial micro-batch stays buffered for
         the next call.
 
-        With ``flush=True`` and nothing already pending, the batch never
-        touches the host feature queue at all: it goes through
-        ``engine.score_device`` — for a padded ``DetectionsBatch`` under
-        the detection extractor + fused MLP that is the one-dispatch
-        boxes→estimates pipeline — and converts once at the policy
-        boundary.  Decisions are identical to the buffered route (both
-        score the same rows as one batch, in arrival order)."""
-        if flush and self._pending_rows == 0 and (
-            features is None or np.ndim(features) == 2
+        With ``flush=True``, nothing already pending and a padded
+        ``DetectionsBatch``, the batch never touches the host feature
+        queue: each micro-batch chunk of images goes through
+        ``engine.score_device`` — under the detection extractor + fused
+        MLP that is the one-dispatch boxes→estimates pipeline.  Both routes
+        score the same rows in the same ``micro_batch`` chunks, so their
+        decisions agree."""
+        if (
+            flush
+            and self._pending_rows == 0
+            and features is None
+            and isinstance(weak_outputs, DetectionsBatch)
         ):
-            est = np.asarray(
-                self.engine.score_device(weak_outputs, features=features),
-                np.float64,
-            ).ravel()
+            est = self._score_chunks(
+                lambda lo, hi: self.engine.score_device(
+                    weak_outputs.slice_images(lo, hi)
+                ),
+                len(weak_outputs),
+            )
             if est.size == 0:
                 return []
             self._next_step += est.size
             return self._decide(est)
         x = np.asarray(self.engine.features(weak_outputs, features=features), np.float32)
         self._enqueue(x)
-        out: List[StepDecision] = []
         if flush:
-            out.extend(self.flush())
-        else:
-            while self._pending_rows >= self.micro_batch:
-                out.extend(self._drain(self.micro_batch))
-        return out
+            return self.flush()
+        full = self._pending_rows - self._pending_rows % self.micro_batch
+        return self._drain(full)
 
     def _enqueue(self, block: np.ndarray) -> None:
         if block.ndim != 2:
@@ -478,29 +481,43 @@ class OffloadSession:
         self._next_step += rows
 
     def flush(self) -> List[StepDecision]:
-        """Score everything pending (one fused-kernel call) and decide each
-        frame in arrival order through the session policy."""
+        """Score everything pending and decide each frame in arrival order
+        through the session policy."""
         return self._drain(self._pending_rows)
 
+    def _score_chunks(
+        self, score: Callable[[int, int], Any], rows: int
+    ) -> np.ndarray:
+        """Estimates for rows ``[0, rows)`` scored as consecutive
+        ``micro_batch`` chunks — the one row partition every route uses, so
+        a backend whose reductions depend on the row count still gives
+        each frame the same estimate on every route.  All chunks are
+        dispatched before the first is read back."""
+        mb = self.micro_batch
+        parts = [score(lo, min(lo + mb, rows)) for lo in range(0, rows, mb)]
+        if not parts:
+            return np.zeros((0,), np.float64)
+        return np.concatenate([np.asarray(p, np.float64).ravel() for p in parts])
+
     def _drain(self, rows: int) -> List[StepDecision]:
-        """Score the first ``rows`` pending frames as one batch and decide
-        them in arrival order."""
-        if rows <= 0 or not self._pending_rows:
-            return []
+        """Score the first ``rows`` pending frames and decide them in
+        arrival order."""
         rows = min(rows, self._pending_rows)
-        head = self._buf[:rows]
+        if rows <= 0:
+            return []
+        buf = self._buf
         prof = self._profiler
+
+        def score(lo: int, hi: int):
+            return self.engine.score_device(features=buf[lo:hi])
+
         # device scoring; one host conversion at the policy boundary (the
         # estimates are materialized before the buffer is compacted)
         if prof is None:
-            estimates = np.asarray(
-                self.engine.score_device(features=head), np.float64
-            ).ravel()
+            estimates = self._score_chunks(score, rows)
         else:
             t0 = prof.begin()
-            estimates = np.asarray(
-                self.engine.score_device(features=head), np.float64
-            ).ravel()
+            estimates = self._score_chunks(score, rows)
             prof.add("session.score", t0)
         rem = self._pending_rows - rows
         if rem:

@@ -62,6 +62,18 @@ def test_batch_round_trip_with_empty_images():
     assert db.max_boxes >= 8  # padded to the bucket floor
 
 
+@pytest.mark.parametrize("cls", [DetectionsBatch, GroundTruthBatch])
+def test_slice_images_keeps_type_and_rows(noisy_pair, cls):
+    gts, weak, _ = noisy_pair
+    batch = cls.from_list(weak if cls is DetectionsBatch else gts)
+    part = batch.slice_images(3, 9)
+    assert type(part) is cls and len(part) == 6
+    assert part.max_boxes == batch.max_boxes
+    for i in range(6):
+        np.testing.assert_array_equal(part[i].boxes, batch[3 + i].boxes)
+    assert len(batch.slice_images(4, 4)) == 0
+
+
 def test_from_list_empty_is_explicit_zero_length_batch():
     """``from_list([])`` is a well-defined zero-length batch for BOTH
     containers — not an incidental numpy stack error."""

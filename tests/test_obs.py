@@ -198,6 +198,13 @@ def test_jit_stats_sites_registered_by_kernel_imports():
     assert "features.box_feature_stack" in sites
 
 
+def test_register_jit_refuses_plain_function():
+    """A site without a jit cache would have no retrace count to report."""
+    with pytest.raises(TypeError, match="jax.jit"):
+        jit_stats.register_jit("test.plain_function", lambda x: x)
+    assert "test.plain_function" not in jit_stats.sites()
+
+
 def test_jit_stats_counts_retraces(engine_and_features):
     eng, x = engine_and_features
     before = jit_stats.snapshot()

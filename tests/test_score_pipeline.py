@@ -231,6 +231,26 @@ def test_session_buffer_interleaving(fitted_engine):
     np.testing.assert_allclose([d.estimate for d in out], ref, rtol=0, atol=0)
 
 
+def test_session_flush_scores_micro_batch_chunks(fitted_engine):
+    """A flush with more than ``micro_batch`` rows pending scores them in
+    the same consecutive chunks as every other route."""
+    from repro.runtime.session import OffloadSession
+
+    rng = np.random.default_rng(17)
+    blocks = [make_batch(rng, 5, 20), make_batch(rng, 40, 20)]
+    sess = OffloadSession(fitted_engine, micro_batch=16)
+    out = sess.submit_batch(blocks[0], flush=False)
+    out += sess.submit_batch(blocks[1])  # 45 pending, flushed at once
+    assert [d.step for d in out] == list(range(45))
+    feats = np.concatenate(
+        [extract_features_batch(db, NUM_CLASSES, TOP_K) for db in blocks]
+    )
+    ref = np.concatenate(
+        [fitted_engine.score(features=feats[s : s + 16]) for s in range(0, 45, 16)]
+    )
+    np.testing.assert_allclose([d.estimate for d in out], ref, rtol=0, atol=0)
+
+
 @pytest.mark.parametrize("n_blocks", [40])
 def test_session_buffer_growth_property(fitted_engine, n_blocks):
     """Property sweep (hypothesis when available): random block sizes and
