@@ -300,16 +300,17 @@ class FleetRuntime:
                 self.plane.score(self.engine, x), np.float64
             ).ravel()
         else:
-            t0 = prof.begin()
+            first = self._tick * self.n_streams
+            t0 = prof.begin("fleet.poll", first, self.n_streams)
             for sh in self.shards:
                 sh.dispatcher.poll(now)
             prof.add("fleet.poll", t0)
-            t0 = prof.begin()
+            t0 = prof.begin("fleet.score", first, self.n_streams)
             estimates = np.asarray(
                 self.plane.score(self.engine, x), np.float64
             ).ravel()
             prof.add("fleet.score", t0)
-            t0 = prof.begin()
+            t0 = prof.begin("fleet.decide_dispatch", first, self.n_streams)
         offload = np.zeros(self.n_streams, bool)
         outcome = np.zeros(self.n_streams, np.int8)
         latency = np.full(self.n_streams, np.nan)
@@ -340,7 +341,7 @@ class FleetRuntime:
                         )
         if prof is not None:
             prof.add("fleet.decide_dispatch", t0)
-            t0 = prof.begin()
+            t0 = prof.begin("fleet.redistribute", first, self.n_streams)
         if self.staleness_probe is not None:
             for sh in self.shards:
                 self.budget.record_staleness(

@@ -30,6 +30,9 @@ Three sub-planes, each independently disableable:
   exported as Chrome-trace JSON.
 - :attr:`Obs.profiler` — a :class:`~repro.obs.profiler.DispatchProfiler`
   attributing host-loop wall time to named serve phases.
+  ``Obs(annotate=True)`` builds an
+  :class:`~repro.obs.profiler.AnnotatingProfiler` instead, which also
+  writes each phase into a running ``jax.profiler`` trace.
 
 JIT visibility rides along for free: kernels register their jit entry
 points with :mod:`repro.obs.jit_stats` at import time; ``Obs`` snapshots
@@ -48,7 +51,7 @@ from repro.obs.metrics import (
     MetricsRegistry,
     DEFAULT_TIME_BUCKETS,
 )
-from repro.obs.profiler import DispatchProfiler
+from repro.obs.profiler import AnnotatingProfiler, DispatchProfiler
 from repro.obs.trace import SIM_TS_SCALE, WALL_TS_SCALE, Tracer
 
 __all__ = [
@@ -59,6 +62,7 @@ __all__ = [
     "Histogram",
     "Tracer",
     "DispatchProfiler",
+    "AnnotatingProfiler",
     "jit_stats",
     "DEFAULT_TIME_BUCKETS",
     "SIM_TS_SCALE",
@@ -74,7 +78,8 @@ class Obs:
     instrumented code skips its emissions (the same guard as
     ``obs=None``, applied per plane).  :meth:`Obs.noop` disables all
     three while still exercising the seam — what the overhead bench
-    measures against.
+    measures against.  ``annotate=True`` makes every profiler phase a
+    ``jax.profiler.TraceAnnotation`` as well (it needs ``profiling``).
     """
 
     __slots__ = ("metrics", "tracer", "profiler", "_jit_baseline")
@@ -85,12 +90,18 @@ class Obs:
         metrics: bool = True,
         tracing: bool = True,
         profiling: bool = True,
+        annotate: bool = False,
         clock: Optional[Callable[[], float]] = None,
     ):
+        if annotate and not profiling:
+            raise ValueError(
+                "annotate=True writes the profiler's phases; it needs profiling=True"
+            )
         self.metrics: Optional[MetricsRegistry] = MetricsRegistry() if metrics else None
         self.tracer: Optional[Tracer] = Tracer(clock=clock) if tracing else None
         self.profiler: Optional[DispatchProfiler] = (
-            DispatchProfiler() if profiling else None
+            (AnnotatingProfiler() if annotate else DispatchProfiler())
+            if profiling else None
         )
         # retraces are reported relative to handle construction: jit caches
         # are process-global, the handle's lifetime scopes them to a run

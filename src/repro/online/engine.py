@@ -314,7 +314,7 @@ class AdaptiveEngine:
         incremental = False
         prof = self._profiler
         if drift_forced or self._since_refit >= self.config.refit_every:
-            t0 = prof.begin() if prof is not None else 0.0
+            t0 = prof.begin("online.refit") if prof is not None else 0.0
             refit = self._full_refit()
             if prof is not None:
                 prof.add("online.refit", t0)
@@ -327,7 +327,7 @@ class AdaptiveEngine:
                 else:
                     self.drift.reset(count_event=False)
         elif self._since_update >= self.config.update_every:
-            t0 = prof.begin() if prof is not None else 0.0
+            t0 = prof.begin("online.incremental") if prof is not None else 0.0
             incremental = self._incremental_update()
             if prof is not None:
                 prof.add("online.incremental", t0)

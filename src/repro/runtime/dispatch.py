@@ -175,10 +175,10 @@ class MultiEdgeDispatcher:
         if prof is None:
             self.poll(now)
         else:
-            t0 = prof.begin()
+            t0 = prof.begin("dispatch.poll", step, 1)
             self.poll(now)
             prof.add("dispatch.poll", t0)
-            t0 = prof.begin()
+            t0 = prof.begin("dispatch.probe_order", step, 1)
         if pin and prefer is None:
             raise ValueError("pin=True needs prefer=<edge index>")
         order = self._probe_order(estimate)
@@ -192,7 +192,7 @@ class MultiEdgeDispatcher:
             )
         if prof is not None:
             prof.add("dispatch.probe_order", t0)
-            t0 = prof.begin()
+            t0 = prof.begin("dispatch.admit", step, 1)
         for i in order:
             lat = self.edges[i].try_admit(now, step, estimate, size_bits)
             if lat is not None:

@@ -19,6 +19,7 @@ loaded artifact.
 """
 from __future__ import annotations
 
+import dataclasses
 from collections import deque
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
@@ -33,6 +34,17 @@ from repro.api.engine import OffloadEngine
 from repro.api.policies import make_policy, policy_context_params
 from repro.detection.batch import DetectionsBatch
 from repro.obs.metrics import Counter, Gauge, Histogram, DEFAULT_TIME_BUCKETS
+
+
+def _host_nbytes(x: Any) -> int:
+    """Bytes of the host (numpy) arrays in a scoring input — a feature
+    block or a padded detection batch; arrays already on the device count
+    0."""
+    if isinstance(x, np.ndarray):
+        return x.nbytes
+    if dataclasses.is_dataclass(x):
+        return sum(_host_nbytes(getattr(x, f.name)) for f in dataclasses.fields(x))
+    return 0
 
 
 @dataclass(frozen=True)
@@ -202,7 +214,13 @@ class OffloadSession:
         (default) the instruments are standalone objects and nothing else
         changes: ``telemetry.as_dict()`` payloads are byte-identical
         either way.  The tracer plane (when on) receives one
-        ``session.flush`` span per scoring drain on track ``tid``.
+        ``session.flush`` span per scoring drain on track ``tid``.  The
+        profiler plane (when on) times the phases
+        ``session.score_enqueue``, ``session.score_wait`` and
+        ``session.decide`` on every route; the metrics plane also counts
+        ``repro_session_transfer_bytes_total{direction="h2d"|"d2h"}``,
+        the host arrays handed to the scoring calls and the estimates
+        read back.
     name : str or None
         Stream label used for this session's metric series; auto-numbered
         within the registry when omitted.
@@ -270,8 +288,16 @@ class OffloadSession:
         self._profiler = obs.profiler if obs is not None else None
         self._tid = int(tid)
         self._flush_t0: Optional[float] = None
-        self._init_instruments(
-            obs.metrics if obs is not None else None, name
+        reg = obs.metrics if obs is not None else None
+        self._init_instruments(reg, name)
+        # host<->device bytes of the scoring calls, summed over the sessions
+        # of the registry; kept only when a metrics plane is on
+        self._transfer = None if reg is None else tuple(
+            reg.counter(
+                "repro_session_transfer_bytes_total", {"direction": d},
+                help=f"bytes of the scoring calls' {d} copies",
+            )
+            for d in ("h2d", "d2h")
         )
 
     def _init_instruments(self, reg, name: Optional[str]) -> None:
@@ -441,10 +467,7 @@ class OffloadSession:
             and isinstance(weak_outputs, DetectionsBatch)
         ):
             est = self._score_chunks(
-                lambda lo, hi: self.engine.score_device(
-                    weak_outputs.slice_images(lo, hi)
-                ),
-                len(weak_outputs),
+                len(weak_outputs), weak_outputs.slice_images, self.engine.score_device
             )
             if est.size == 0:
                 return []
@@ -486,18 +509,36 @@ class OffloadSession:
         return self._drain(self._pending_rows)
 
     def _score_chunks(
-        self, score: Callable[[int, int], Any], rows: int
+        self,
+        rows: int,
+        chunk: Callable[[int, int], Any],
+        score: Callable[[Any], Any],
     ) -> np.ndarray:
-        """Estimates for rows ``[0, rows)`` scored as consecutive
-        ``micro_batch`` chunks — the one row partition every route uses, so
-        a backend whose reductions depend on the row count still gives
-        each frame the same estimate on every route.  All chunks are
-        dispatched before the first is read back."""
-        mb = self.micro_batch
-        parts = [score(lo, min(lo + mb, rows)) for lo in range(0, rows, mb)]
-        if not parts:
+        """Estimates for rows ``[0, rows)``: ``score(chunk(lo, hi))`` over
+        consecutive ``micro_batch`` chunks — the one row partition every
+        route uses, so a backend whose reductions depend on the row count
+        still gives each frame the same estimate on every route.  All
+        chunks are dispatched (phase ``session.score_enqueue``) before the
+        first is read back (``session.score_wait``)."""
+        if rows <= 0:
             return np.zeros((0,), np.float64)
-        return np.concatenate([np.asarray(p, np.float64).ravel() for p in parts])
+        mb, prof = self.micro_batch, self._profiler
+        first = self._next_step - self._pending_rows
+        if prof is not None:
+            t0 = prof.begin("session.score_enqueue", first, rows)
+        inputs = [chunk(lo, min(lo + mb, rows)) for lo in range(0, rows, mb)]
+        parts = [score(x) for x in inputs]
+        if prof is not None:
+            prof.add("session.score_enqueue", t0)
+            t0 = prof.begin("session.score_wait", first, rows)
+        est = np.concatenate([np.asarray(p, np.float64).ravel() for p in parts])
+        if prof is not None:
+            prof.add("session.score_wait", t0)
+        if self._transfer is not None:
+            h2d, d2h = self._transfer
+            h2d.inc(sum(_host_nbytes(x) for x in inputs))
+            d2h.inc(sum(int(p.nbytes) for p in parts))
+        return est
 
     def _drain(self, rows: int) -> List[StepDecision]:
         """Score the first ``rows`` pending frames and decide them in
@@ -505,30 +546,17 @@ class OffloadSession:
         rows = min(rows, self._pending_rows)
         if rows <= 0:
             return []
-        buf = self._buf
-        prof = self._profiler
-
-        def score(lo: int, hi: int):
-            return self.engine.score_device(features=buf[lo:hi])
-
+        buf, score = self._buf, self.engine.score_device
         # device scoring; one host conversion at the policy boundary (the
         # estimates are materialized before the buffer is compacted)
-        if prof is None:
-            estimates = self._score_chunks(score, rows)
-        else:
-            t0 = prof.begin()
-            estimates = self._score_chunks(score, rows)
-            prof.add("session.score", t0)
+        estimates = self._score_chunks(
+            rows, lambda lo, hi: buf[lo:hi], lambda x: score(features=x)
+        )
         rem = self._pending_rows - rows
         if rem:
             self._buf[:rem] = self._buf[rows : self._pending_rows].copy()
         self._pending_rows = rem
-        if prof is None:
-            return self._decide(estimates)
-        t0 = prof.begin()
-        out = self._decide(estimates)
-        prof.add("session.decide", t0)
-        return out
+        return self._decide(estimates)
 
     def submit_scored(self, estimates: np.ndarray) -> List[StepDecision]:
         """Decide a block of already-scored frames in arrival order — the
@@ -548,7 +576,14 @@ class OffloadSession:
 
     def _decide(self, estimates: np.ndarray) -> List[StepDecision]:
         """Run already-scored estimates through the session policy in
-        arrival order and account them in the telemetry."""
+        arrival order and account them in the telemetry (phase
+        ``session.decide``)."""
+        # the queue held exactly the arrivals not yet decided, so the drained
+        # rows are the arrival indices trailing the still-pending ones
+        first = self._next_step - self._pending_rows - len(estimates)
+        prof = self._profiler
+        if prof is not None:
+            pt0 = prof.begin("session.decide", first, len(estimates))
         if getattr(self.policy, "batch_budget", False):
             # a per-batch budget (topk) would make streaming decisions
             # depend on micro-batch/flush boundaries (and offload nothing
@@ -562,9 +597,6 @@ class OffloadSession:
             # decide_batch is buffer-invariant here: vectorized for
             # threshold, internally sequential for token_bucket
             offload = np.asarray(self.policy.decide_batch(estimates), bool)
-        # the queue held exactly the arrivals not yet decided, so the drained
-        # rows are the arrival indices trailing the still-pending ones
-        first = self._next_step - self._pending_rows - len(estimates)
         n_off = int(offload.sum())
         self._processed.inc(len(estimates))
         self._offloaded.inc(n_off)
@@ -578,10 +610,13 @@ class OffloadSession:
                 args={"frames": len(estimates), "offloaded": n_off},
             )
             self._flush_t0 = now if self._pending_rows else None
-        return [
+        out = [
             StepDecision(step=first + i, estimate=float(est), offload=bool(off))
             for i, (est, off) in enumerate(zip(estimates, offload))
         ]
+        if prof is not None:
+            prof.add("session.decide", pt0)
+        return out
 
     # --------------------------------------------------------------- control
 

@@ -410,7 +410,7 @@ class OffloadRuntime:
         if prof is None:
             x = self.engine.features(weak_outputs, features=features)
         else:
-            t0 = prof.begin()
+            t0 = prof.begin("serve.features")
             x = self.engine.features(weak_outputs, features=features)
             prof.add("serve.features", t0)
         session = self.open_session(ratio=ratio, micro_batch=micro_batch)
@@ -470,10 +470,10 @@ class OffloadRuntime:
                     settle(session.flush())
                     session.set_ratio(rebudget[step])
                 t_arrival[step] = self.clock()
-                t0 = prof.begin()
+                t0 = prof.begin("serve.submit", step, 1)
                 decisions = session.submit(features=row)
                 prof.add("serve.submit", t0)
-                t0 = prof.begin()
+                t0 = prof.begin("serve.settle", step, len(decisions))
                 settle(decisions)
                 prof.add("serve.settle", t0)
                 self.clock.advance(arrival_period)

@@ -263,10 +263,10 @@ class VideoRuntime(OffloadRuntime):
             if prof is None:
                 tf = tracker.update(weak.frame(t))
             else:
-                _pt0 = prof.begin()
+                _pt0 = prof.begin("video.track", t * B, B)
                 tf = tracker.update(weak.frame(t))
                 prof.add("video.track", _pt0)
-                _pt0 = prof.begin()
+                _pt0 = prof.begin("video.serve_frames", t * B, B)
             churn = tf.churn()
             for b, (st, session) in enumerate(zip(streams, sessions)):
                 st["frame"] = t
@@ -325,7 +325,7 @@ class VideoRuntime(OffloadRuntime):
 
         # score what was actually served, one batched matcher call
         if prof is not None:
-            _pt0 = prof.begin()
+            _pt0 = prof.begin("video.score_accuracy", 0, B * T)
         acc = frame_accuracies(
             [d for per in served for d in per],
             [clip.gt(t, b) for b in range(B) for t in range(T)],

@@ -221,13 +221,80 @@ def test_profiler_report_shares_sum_to_one():
     prof = DispatchProfiler()
     for phase, n in (("a", 3), ("b", 2)):
         for _ in range(n):
-            t0 = prof.begin()
+            t0 = prof.begin(phase)
             prof.add(phase, t0)
     rep = prof.report()
     assert set(rep) == {"a", "b"}
     assert sum(row["share"] for row in rep.values()) == pytest.approx(1.0)
     assert {phase: row["count"] for phase, row in rep.items()} == {"a": 3, "b": 2}
     assert "phase" in prof.format_report()
+
+
+def test_profiler_report_max_ms_at_least_mean():
+    prof = DispatchProfiler()
+    for spin in (0, 2000, 50):
+        t0 = prof.begin("a")
+        sum(range(spin))
+        prof.add("a", t0)
+    row = prof.report()["a"]
+    assert row["count"] == 3
+    assert row["max_ms"] >= row["mean_us"] / 1e3 > 0.0
+    assert row["max_ms"] <= row["total_ms"]
+    assert "max ms" in prof.format_report()
+
+
+def _host_events(trace_dir):
+    """``(name, stats)`` of every event on the host plane of the one
+    profiler trace under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return [
+        (e.name, {k: v for k, v in e.stats})
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    ]
+
+
+@pytest.mark.parametrize("annotate", [True, False])
+def test_profiler_phases_in_jax_trace_only_when_annotating(tmp_path, annotate):
+    """The annotating profiler writes each phase as a host event of the
+    same name with its ``step``/``frames`` stats; the plain one writes
+    nothing into the trace."""
+    import jax
+
+    from repro.obs import AnnotatingProfiler
+
+    obs = Obs(metrics=False, tracing=False, annotate=annotate)
+    prof = obs.profiler
+    assert isinstance(prof, AnnotatingProfiler) is annotate
+    jax.profiler.start_trace(str(tmp_path))
+    t0 = prof.begin("session.decide", 40, 24)
+    t1 = prof.begin("dispatch.admit")
+    prof.add("dispatch.admit", t1)
+    prof.add("session.decide", t0)
+    jax.profiler.stop_trace()
+    events = dict(
+        (n, st) for n, st in _host_events(tmp_path)
+        if n in ("session.decide", "dispatch.admit")
+    )
+    if annotate:
+        assert events == {
+            "session.decide": {"step": 40, "frames": 24},
+            "dispatch.admit": {},
+        }
+    else:
+        assert events == {}
+    assert obs.profiler.report()["session.decide"]["count"] == 1
+
+
+def test_annotate_needs_profiling():
+    with pytest.raises(ValueError, match="profiling"):
+        Obs(profiling=False, annotate=True)
 
 
 # ------------------------------------------------------------- obs handle
@@ -381,7 +448,10 @@ def test_simulate_profiler_attributes_phases(engine_and_features):
         ratio=0.3, micro_batch=16, seed=0, obs=obs,
     )
     phases = obs.profiler.totals()
-    assert {"serve.submit", "serve.settle", "session.score"} <= set(phases)
+    assert {
+        "serve.submit", "serve.settle",
+        "session.score_enqueue", "session.score_wait", "session.decide",
+    } <= set(phases)
 
 
 def test_adaptive_engine_obs_counters(engine_and_features):

@@ -280,3 +280,117 @@ def test_session_buffer_growth_property(fitted_engine, n_blocks):
         assert sess._pending_rows == 0
 
     check()
+
+
+# ------------------------------------------------ session phases and bytes
+
+SESSION_PHASES = ("session.score_enqueue", "session.score_wait", "session.decide")
+
+
+def _serve_blocks(engine, blocks, route, obs, micro_batch=16):
+    """Decisions for ``blocks`` served one ``submit_batch`` each, through
+    the fused ``DetectionsBatch`` route or the buffered feature route."""
+    from repro.runtime.session import OffloadSession
+
+    sess = OffloadSession(engine, micro_batch=micro_batch, obs=obs)
+    out = []
+    for db in blocks:
+        if route == "fused":
+            out += sess.submit_batch(db)
+        else:
+            out += sess.submit_batch(
+                features=extract_features_batch(db, NUM_CLASSES, TOP_K)
+            )
+    return sess, out
+
+
+def _session_events(trace_dir):
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return sorted(
+        (dict(e.stats)["step"], e.name, dict(e.stats)["frames"])
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+        if e.name in SESSION_PHASES
+    )
+
+
+@pytest.mark.parametrize("route", ["fused", "buffered"])
+def test_session_routes_record_phases_on_the_trace(fitted_engine, route, tmp_path):
+    """Both routes time enqueue, wait and decide once per block, and with
+    an annotating handle each phase is a host event of the profiler trace
+    carrying the block's first step and its frames."""
+    import jax
+
+    from repro.obs import Obs
+
+    rng = np.random.default_rng(23)
+    blocks = [make_batch(rng, n, 20) for n in (40, 17)]  # 3 + 2 chunks of 16
+    obs = Obs(tracing=False, annotate=True)
+    jax.profiler.start_trace(str(tmp_path))
+    sess, out = _serve_blocks(fitted_engine, blocks, route, obs)
+    jax.profiler.stop_trace()
+    assert len(out) == 57
+    rep = obs.profiler.report()
+    assert {p: rep[p]["count"] for p in SESSION_PHASES} == dict.fromkeys(SESSION_PHASES, 2)
+    assert _session_events(tmp_path) == sorted(
+        (step, p, n) for step, n in ((0, 40), (40, 17)) for p in SESSION_PHASES
+    )
+    # one float32 estimate read back per frame, over 5 chunks
+    snap = obs.metrics.snapshot()
+    assert snap['repro_session_transfer_bytes_total{direction="d2h"}'] == 4 * 57
+
+
+@pytest.mark.parametrize("obs_kind", ["none", "plain"])
+def test_session_without_annotation_writes_no_phase(fitted_engine, obs_kind, tmp_path):
+    import jax
+
+    from repro.obs import Obs
+
+    obs = None if obs_kind == "none" else Obs(tracing=False)
+    jax.profiler.start_trace(str(tmp_path))
+    _serve_blocks(fitted_engine, [make_batch(np.random.default_rng(3), 20, 20)], "fused", obs)
+    jax.profiler.stop_trace()
+    assert _session_events(tmp_path) == []
+    if obs is not None:
+        assert set(SESSION_PHASES) <= set(obs.profiler.totals())
+
+
+@pytest.mark.parametrize("route", ["fused", "buffered"])
+def test_session_transfer_bytes_counter(fitted_engine, route):
+    """h2d counts the host arrays handed to the scoring calls (the padded
+    detection batch, or the feature rows), d2h 4 B per estimate."""
+    from repro.obs import Obs
+
+    db = make_batch(np.random.default_rng(29), 37, 20)
+    obs = Obs(tracing=False, profiling=False)
+    _serve_blocks(fitted_engine, [db], route, obs)
+    if route == "fused":
+        h2d = db.boxes.nbytes + db.scores.nbytes + db.classes.nbytes + db.mask.nbytes
+        assert h2d == 37 * db.max_boxes * (16 + 4 + 4 + 1)
+    else:
+        h2d = extract_features_batch(db, NUM_CLASSES, TOP_K).nbytes
+    snap = obs.metrics.snapshot()
+    assert snap['repro_session_transfer_bytes_total{direction="h2d"}'] == h2d
+    assert snap['repro_session_transfer_bytes_total{direction="d2h"}'] == 4 * 37
+
+
+@pytest.mark.parametrize("route", ["fused", "buffered"])
+def test_session_decisions_identical_under_obs_handles(fitted_engine, route):
+    from repro.obs import Obs
+
+    rng = np.random.default_rng(31)
+    blocks = [make_batch(rng, n, 20) for n in (33, 1, 16)]
+    runs = [
+        _serve_blocks(fitted_engine, blocks, route, obs)
+        for obs in (None, Obs.noop(), Obs(annotate=True))
+    ]
+    base_sess, base = runs[0]
+    for sess, out in runs[1:]:
+        assert out == base
+        assert sess.telemetry.as_dict() == base_sess.telemetry.as_dict()
