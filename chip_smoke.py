@@ -16,6 +16,9 @@ One chip:
             stream
   session   ``OffloadSession.submit_batch``: the fused fast route against
             the buffered route over host features
+  packed    ``score_pipeline`` on host blocks (one packed buffer per call)
+            against the same rows as device arrays, bit for bit, at the
+            COCO head (80 classes, 100 slots, top-100), blocks of 1-64 rows
   tracker   the tracker's ``lax.scan`` with the compiled IoU kernel against
             the pure-Python ``track_clip_ref``
   fleet     ``simulate_fleet`` on the default 1024-stream city on a 1-device
@@ -49,17 +52,19 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.api import DetectionBoxFeatures, MLPRewardModel, OffloadEngine  # noqa: E402
 from repro.core import EstimatorConfig  # noqa: E402
-from repro.core.features import extract_features_batch  # noqa: E402
+from repro.core.features import extract_features_batch, feature_dim  # noqa: E402
 from repro.detection.batch import DetectionsBatch, GroundTruthBatch, match_batch  # noqa: E402
 from repro.fleet import FleetPlane, default_city_scenario, run_city_scenario  # noqa: E402
 from repro.fleet.runtime import simulate_fleet  # noqa: E402
 from repro.kernels.dispatch import resolve_interpret, resolve_path  # noqa: E402
 from repro.kernels.score_pipeline import ops as score_ops  # noqa: E402
 from repro.launch.mesh import make_fleet_mesh  # noqa: E402
+from repro.obs import jit_stats  # noqa: E402
 from repro.runtime import OffloadSession, default_congested_fleet, simulate  # noqa: E402
 from repro.video import (  # noqa: E402
     WEAK_PROFILE,
@@ -87,6 +92,11 @@ EST_TOL = 1e-2
 MIN_CORR = 0.5
 #: tolerance of the tracker's box/velocity/confidence state (test_video's)
 TRACK_TOL = 1e-5
+#: the COCO head of the chip benchmark's camera cell: classes and detector
+#: slots (top-k keeps every slot), and its image size
+COCO_CLASSES, COCO_SLOTS, COCO_IMAGE = 80, 100, 640.0
+#: rows of the packed phase's blocks: ragged chunk tails and a full chunk
+PACKED_ROWS = (1, 2, 7, 13, 31, 38, 63, 64)
 
 
 @dataclass(frozen=True)
@@ -132,17 +142,18 @@ def _check_paths(paths: Paths) -> None:
 
 
 def synth_detections(
-    rng: np.random.Generator, n: int, max_boxes: int = MAX_BOXES
+    rng: np.random.Generator, n: int, max_boxes: int = MAX_BOXES,
+    num_classes: int = NUM_CLASSES, image_size: float = IMAGE_SIZE,
 ) -> DetectionsBatch:
     """``n`` images of padded weak-detector output: 0..max_boxes boxes
     each, uniform corners inside the image, Beta(2, 2) confidences."""
     counts = rng.integers(0, max_boxes + 1, n)
     mask = np.arange(max_boxes)[None, :] < counts[:, None]
-    xy = rng.uniform(0.0, IMAGE_SIZE - 20.0, (n, max_boxes, 2))
+    xy = rng.uniform(0.0, image_size - 20.0, (n, max_boxes, 2))
     wh = rng.uniform(2.0, 20.0, (n, max_boxes, 2))
     boxes = np.concatenate([xy, xy + wh], -1) * mask[..., None]
     scores = rng.beta(2.0, 2.0, (n, max_boxes)) * mask
-    classes = np.where(mask, rng.integers(0, NUM_CLASSES, (n, max_boxes)), -1)
+    classes = np.where(mask, rng.integers(0, num_classes, (n, max_boxes)), -1)
     return DetectionsBatch(boxes=boxes, scores=scores, classes=classes, mask=mask)
 
 
@@ -297,6 +308,47 @@ def phase_session(engine: OffloadEngine, seed: int, paths: Paths,
             f"max|fast-buffered|={delta:.3e} tol={EST_TOL:g}"
             f" decisions_differing={int(differ.sum())}"
         ),
+    }
+
+
+def phase_packed(seed: int, paths: Paths, rows: Tuple[int, ...] = PACKED_ROWS,
+                 per_size: int = 8) -> Dict:
+    """Host blocks cross as one packed buffer per scoring call; their
+    estimates must equal those of the same rows passed as device arrays
+    (the four-array route) bit for bit, on the resolved path and ``lax``."""
+    _check_paths(paths)
+    rng = np.random.default_rng(seed + 6)
+    F = feature_dim(COCO_CLASSES, COCO_SLOTS)
+    params = {k: jnp.asarray(v, jnp.float32) for k, v in {
+        "w1": rng.normal(0.0, F ** -0.5, (F, HIDDEN)),
+        "b1": rng.normal(0.0, 0.1, HIDDEN),
+        "w2": rng.normal(0.0, HIDDEN ** -0.5, HIDDEN),
+        "b2": rng.normal(0.0, 0.1),
+        "mu": rng.normal(0.0, 0.1, F),
+        "sigma": rng.uniform(0.5, 2.0, F),
+    }.items()}
+    kw = dict(num_classes=COCO_CLASSES, top_k=COCO_SLOTS, image_size=COCO_IMAGE)
+    pipeline_paths = tuple(dict.fromkeys((paths.pipeline, "lax")))
+    worst, unequal, blocks = 0.0, 0, 0
+    calls0 = jit_stats.snapshot()["score_pipeline.packed"][1]
+    for path in pipeline_paths:
+        for n in rows:
+            for _ in range(per_size):
+                db = synth_detections(rng, n, COCO_SLOTS, COCO_CLASSES, COCO_IMAGE)
+                host = np.asarray(score_ops.score_pipeline(db, params, path=path, **kw))
+                on_device = tuple(jax.device_put(a) for a in (db.boxes, db.scores, db.classes, db.mask))
+                dev = np.asarray(score_ops.score_pipeline(on_device, params, path=path, **kw))
+                unequal += int(not np.array_equal(host, dev))
+                worst = max(worst, float(np.max(np.abs(host - dev))))
+                blocks += 1
+    packed = jit_stats.snapshot()["score_pipeline.packed"][1] - calls0
+    check(unequal == 0, f"{unequal} of {blocks} packed blocks differ, max|packed-arrays|={worst:.3g}")
+    check(packed == blocks, f"{packed} packed calls for {blocks} host blocks")
+    return {
+        "shapes": (f"classes={COCO_CLASSES},slots={COCO_SLOTS},top_k={COCO_SLOTS},"
+                   f"rows={min(rows)}-{max(rows)},blocks_per_path={blocks // len(pipeline_paths)}"),
+        "check": (f"paths={','.join(pipeline_paths)} max|packed-arrays|={worst:.3e}"
+                  f" bit_identical_blocks={blocks - unequal}/{blocks} packed_calls={packed}"),
     }
 
 
@@ -504,6 +556,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                 ("decide", lambda: phase_decide(engine, seed, paths)),
                 ("simulate", lambda: phase_simulate(engine, seed, paths)),
                 ("session", lambda: phase_session(engine, seed, paths)),
+                ("packed", lambda: phase_packed(seed, paths)),
                 ("tracker", lambda: phase_tracker(seed, paths)),
                 ("fleet", lambda: phase_fleet(seed, paths)),
             ]

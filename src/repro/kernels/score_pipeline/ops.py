@@ -23,6 +23,12 @@ MLP — runs as ONE jitted dispatch:
 (TPU/GPU), ``"lax"`` on CPU (the interpreter would be slower than the jit
 — the same reasoning as ``repro.kernels.dispatch.resolve_path``).
 
+A block of host (numpy) arrays crosses to the device as ONE packed
+``uint8`` buffer (:func:`pack_detections`), unpacked bit for bit inside the
+jitted program; arrays already on the device go in as they are.  The
+``image_size`` divisor is a cached device scalar, so a scoring call makes
+at most one host→device transfer.
+
 No path donates its inputs: a caller may score the same device-resident
 block twice (``decide`` after ``score_device``, or a plain reference next
 to the fused path), and a donated block would be deleted by the first call.
@@ -35,6 +41,7 @@ from typing import Dict, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.features import feature_dim
 from repro.detection.batch import DetectionsBatch
@@ -44,7 +51,7 @@ from repro.kernels.score_pipeline.kernel import (
     score_pipeline_pallas,
 )
 from repro.kernels.score_pipeline.ref import score_pipeline_ref
-from repro.obs.jit_stats import register_jit
+from repro.obs.jit_stats import count_call, register_jit
 
 PIPELINE_PATHS = ("lax", "pallas", "pallas_interpret")
 
@@ -165,6 +172,75 @@ def _score_pipeline_pallas(
 
 register_jit("score_pipeline.pallas", _score_pipeline_pallas)
 
+#: dtypes of the four detection arrays, in packing order: boxes, scores,
+#: classes, mask — the dtypes ``DetectionsBatch`` holds
+_PACKED_DTYPES = (np.dtype(np.float32), np.dtype(np.float32),
+                  np.dtype(np.int32), np.dtype(np.bool_))
+#: bytes of one detection slot in a packed row: boxes 16, score 4, class 4,
+#: mask 1
+PACKED_SLOT_BYTES = 25
+
+
+def pack_detections(boxes, scores, classes, mask) -> np.ndarray:
+    """One fresh ``(B, 25 K)`` ``uint8`` buffer holding a host detection
+    block, planar: each row is its boxes' bytes, then its scores', classes'
+    and mask's.  A new buffer per call: a transfer may still be reading the
+    last one when the next chunk is packed."""
+    B = scores.shape[0]
+    return np.concatenate(
+        [np.ascontiguousarray(a).reshape(B, -1).view(np.uint8)
+         for a in (boxes, scores, classes, mask)],
+        axis=1,
+    )
+
+
+def unpack_detections(packed):
+    """Inverse of :func:`pack_detections` inside a jitted program: the
+    (boxes, scores, classes, mask) arrays, bit for bit."""
+    B, K = packed.shape[0], packed.shape[1] // PACKED_SLOT_BYTES
+
+    def words(lo, hi, shape, dtype):  # bytes [lo K, hi K) of every row
+        seg = packed[:, lo * K:hi * K].reshape((B, K) + shape + (4,))
+        return lax.bitcast_convert_type(seg, dtype)
+
+    return (
+        words(0, 16, (4,), jnp.float32),
+        words(16, 20, (), jnp.float32),
+        words(20, 24, (), jnp.int32),
+        packed[:, 24 * K:] != 0,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("num_classes", "top_k", "tile_b", "path")
+)
+def _score_packed(
+    packed, w1, b1, w2, b2, mu, sigma, image_size,
+    num_classes, top_k, tile_b, path,
+):
+    """The scoring program of a packed host block: unpack, then exactly the
+    body the four-array route runs on ``path``."""
+    det = unpack_detections(packed)
+    if path == "lax":
+        return score_pipeline_ref(
+            *det, w1, b1, w2, b2, mu, sigma, image_size, num_classes, top_k
+        )
+    return _score_pipeline_pallas(
+        *det, w1, b1, w2, b2, mu, sigma, image_size,
+        num_classes, top_k, tile_b, path == "pallas_interpret",
+    )
+
+
+register_jit("score_pipeline.packed", _score_packed)
+
+
+@functools.lru_cache(maxsize=16)
+def _device_scalar(value: float) -> jax.Array:
+    """The float32 ``value`` on the device, put there once.  Not a static
+    argument: a constant divisor would let XLA rewrite the division and
+    break bit-identity with the composed route."""
+    return jax.device_put(np.float32(value))
+
 
 def score_pipeline(
     batch: Union[DetectionsBatch, Tuple],
@@ -180,15 +256,16 @@ def score_pipeline(
 
     ``batch`` is a :class:`DetectionsBatch` or a ``(boxes, scores,
     classes, mask)`` tuple of (possibly already device-resident) arrays;
-    ``params`` comes from :func:`pipeline_params`.  The result stays a
-    ``jnp`` array — callers convert once at the policy boundary.
+    ``params`` comes from :func:`pipeline_params`.  Host arrays of the
+    batch's dtypes cross as one packed buffer (call site
+    ``score_pipeline.packed``); any other arrays go into the jit as they
+    are.  The result stays a ``jnp`` array — callers convert once at the
+    policy boundary.
     """
     if isinstance(batch, DetectionsBatch):
         arrays = (batch.boxes, batch.scores, batch.classes, batch.mask)
     else:
         arrays = tuple(batch)
-    # host arrays go straight into the jit (it converts on dispatch) — an
-    # eager jnp.asarray here would cost four extra op dispatches per block
     boxes, scores, classes, mask = arrays
     F = int(params["w1"].shape[0])
     expect = feature_dim(int(num_classes), int(top_k))
@@ -200,16 +277,23 @@ def score_pipeline(
     if scores.shape[0] == 0:
         return jnp.zeros((0,), jnp.float32)
     resolved = resolve_pipeline_path(path)
-    p = params
+    weights = tuple(params[k] for k in ("w1", "b1", "w2", "b2", "mu", "sigma"))
+    size = _device_scalar(float(image_size))
+    if all(
+        isinstance(a, np.ndarray) and a.dtype == d
+        for a, d in zip(arrays, _PACKED_DTYPES)
+    ):
+        count_call("score_pipeline.packed")
+        return _score_packed(
+            pack_detections(*arrays), *weights, size, int(num_classes),
+            int(top_k), int(tile_b), resolved,
+        )
+    # device-resident (or other-dtype) arrays go straight into the jit
     if resolved == "lax":
         return _score_pipeline_lax(
-            boxes, scores, classes, mask,
-            p["w1"], p["b1"], p["w2"], p["b2"], p["mu"], p["sigma"],
-            np.float32(image_size), int(num_classes), int(top_k),
+            *arrays, *weights, size, int(num_classes), int(top_k),
         )
     return _score_pipeline_pallas(
-        boxes, scores, classes, mask,
-        p["w1"], p["b1"], p["w2"], p["b2"], p["mu"], p["sigma"],
-        np.float32(image_size), int(num_classes), int(top_k),
+        *arrays, *weights, size, int(num_classes), int(top_k),
         int(tile_b), resolved == "pallas_interpret",
     )

@@ -23,10 +23,12 @@ per-run scoping happens by snapshot-delta, not by registry instance —
 """
 from __future__ import annotations
 
+import threading
 from typing import Any, Dict, Tuple
 
 _SITES: Dict[str, Any] = {}
 _CALLS: Dict[str, int] = {}
+_CALLS_LOCK = threading.Lock()
 
 
 def register_jit(site: str, fn: Any) -> Any:
@@ -42,18 +44,22 @@ def register_jit(site: str, fn: Any) -> Any:
 
 def count_call(site: str, n: int = 1) -> None:
     """Manual call counter for sites that rebuild their jits per call
-    (shard_map closures) — a dict increment, nothing more."""
-    _CALLS[site] = _CALLS.get(site, 0) + n
+    (shard_map closures) or that count one route of a jit — a locked dict
+    increment, since scoring calls may come from several threads."""
+    with _CALLS_LOCK:
+        _CALLS[site] = _CALLS.get(site, 0) + n
 
 
 def snapshot() -> Dict[str, Tuple[int, int]]:
     """``{site: (traces, calls)}`` — ``traces`` is the jit cache size
     (distinct compiled specializations so far), ``calls`` the manual
     counter (0 unless the site uses :func:`count_call`)."""
+    with _CALLS_LOCK:
+        calls = dict(_CALLS)
     out: Dict[str, Tuple[int, int]] = {}
     for site, fn in _SITES.items():
-        out[site] = (int(fn._cache_size()), _CALLS.get(site, 0))
-    for site, n in _CALLS.items():
+        out[site] = (int(fn._cache_size()), calls.get(site, 0))
+    for site, n in calls.items():
         if site not in _SITES:
             out[site] = (0, n)
     return out

@@ -67,6 +67,22 @@ def test_session_phase_routes_agree_exactly(engine):
     assert "decisions_differing=0" in out["check"]
 
 
+def test_packed_phase_bit_identical_interpreted(monkeypatch):
+    auto = score_ops.resolve_pipeline_path
+    monkeypatch.setattr(
+        score_ops, "resolve_pipeline_path",
+        lambda path=None: "pallas_interpret" if path is None else auto(path),
+    )
+    paths = cs.Paths(
+        pipeline="pallas_interpret", kernel="reference",
+        tracker_interpret=True, mosaic=False,
+    )
+    out = cs.phase_packed(0, paths, rows=(1, 5), per_size=2)
+    assert "paths=pallas_interpret,lax" in out["check"]
+    assert "max|packed-arrays|=0.000e+00" in out["check"]
+    assert "bit_identical_blocks=8/8 packed_calls=8" in out["check"]
+
+
 def test_tracker_phase():
     out = cs.phase_tracker(0, CPU, n_streams=2, n_frames=8)
     assert out["check"].startswith("association==track_clip_ref")

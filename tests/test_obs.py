@@ -205,6 +205,33 @@ def test_register_jit_refuses_plain_function():
     assert "test.plain_function" not in jit_stats.sites()
 
 
+def test_count_call_loses_no_update_across_threads():
+    """Scoring calls count from several threads at once (the warm-up of
+    ragged chunk programs does); no increment may be lost."""
+    import sys
+    import threading
+
+    site, n_threads, per_thread = "test.threaded_count", 16, 2000
+    before = jit_stats.snapshot().get(site, (0, 0))[1]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [
+            threading.Thread(
+                target=lambda: [jit_stats.count_call(site) for _ in range(per_thread)]
+            )
+            for _ in range(n_threads)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert jit_stats.snapshot()[site][1] - before == n_threads * per_thread
+
+
 def test_jit_stats_counts_retraces(engine_and_features):
     eng, x = engine_and_features
     before = jit_stats.snapshot()

@@ -17,6 +17,11 @@ from repro.kernels.score_pipeline import (
     resolve_pipeline_path,
     score_pipeline,
 )
+from repro.kernels.score_pipeline.ops import (
+    PACKED_SLOT_BYTES,
+    pack_detections,
+    unpack_detections,
+)
 
 NUM_CLASSES = 7
 TOP_K = 25
@@ -135,6 +140,83 @@ def test_score_pipeline_accepts_array_tuple(fitted_engine):
         )
     )
     np.testing.assert_array_equal(via_batch, via_tuple)
+
+
+def _packed_calls():
+    from repro.obs import jit_stats
+
+    return jit_stats.snapshot()["score_pipeline.packed"][1]
+
+
+def _device_arrays(db):
+    return (jnp.asarray(db.boxes), jnp.asarray(db.scores),
+            jnp.asarray(db.classes), jnp.asarray(db.mask))
+
+
+@pytest.mark.parametrize("path", ["lax", "pallas_interpret"])
+@pytest.mark.parametrize("B", [1, 37, 64])
+@pytest.mark.parametrize("K", [12, TOP_K, 40])
+def test_packed_route_bit_identical_to_device_arrays(fitted_engine, path, B, K):
+    """A host block crosses as one packed buffer; its estimates equal those
+    of the same rows passed as device arrays (the four-array route) bit for
+    bit, with the box axis below, at and above ``top_k``, all-masked rows
+    and ``-1`` class slots."""
+    rng = np.random.default_rng(1000 * B + K)
+    db = make_batch(rng, B, K, frac_empty=0.3)
+    db = DetectionsBatch.from_list(db.to_list(), max_boxes=K)
+    db.mask[0] = False  # an all-masked row whatever the draw
+    db.classes[db.mask & (rng.uniform(size=db.mask.shape) < 0.1)] = -1
+    params = fitted_engine.reward_model.pipeline_params()
+    kw = dict(num_classes=NUM_CLASSES, top_k=TOP_K, image_size=64.0, path=path)
+    calls = _packed_calls()
+    packed = np.asarray(score_pipeline(db, params, **kw))
+    assert _packed_calls() == calls + 1
+    unpacked = np.asarray(score_pipeline(_device_arrays(db), params, **kw))
+    assert _packed_calls() == calls + 1
+    assert packed.shape == (B,)
+    np.testing.assert_array_equal(packed, unpacked)
+
+
+def test_unpack_detections_round_trips_every_bit():
+    """NaN payloads, signed zeros, subnormals and negative classes come
+    back from the packed buffer with the same bits."""
+    import jax
+
+    rng = np.random.default_rng(7)
+    B, K = 3, 5
+    bits = rng.integers(0, 2**32, (B, K, 4), dtype=np.uint64).astype(np.uint32)
+    boxes = bits.view(np.float32)
+    scores = np.array([np.nan, -0.0, 1e-45, -np.inf, 0.5] * B, np.float32).reshape(B, K)
+    classes = rng.integers(-(2**31), 2**31, (B, K), dtype=np.int64).astype(np.int32)
+    mask = rng.uniform(size=(B, K)) < 0.5
+    packed = pack_detections(boxes, scores, classes, mask)
+    assert packed.dtype == np.uint8 and packed.shape == (B, PACKED_SLOT_BYTES * K)
+    out = jax.jit(unpack_detections)(packed)
+    for got, want in zip(out, (boxes, scores, classes, mask)):
+        got = np.asarray(got)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
+def test_packed_calls_counted_per_chunk(fitted_engine):
+    """``score_pipeline.packed`` counts one call per ``micro_batch`` chunk
+    of the session's fused route, and none for device-resident inputs or
+    the buffered feature route."""
+    from repro.obs import Obs
+
+    site = 'repro_jit_calls_total{site="score_pipeline.packed"}'
+    rng = np.random.default_rng(37)
+    blocks = [make_batch(rng, n, 20) for n in (40, 17)]  # 3 + 2 chunks of 16
+    obs = Obs(tracing=False, profiling=False)
+    _serve_blocks(fitted_engine, blocks, "fused", obs)
+    assert obs.metrics.snapshot()[site] == 5
+
+    obs = Obs(tracing=False, profiling=False)
+    params = fitted_engine.reward_model.pipeline_params()
+    score_pipeline(_device_arrays(blocks[0]), params,
+                   num_classes=NUM_CLASSES, top_k=TOP_K).block_until_ready()
+    _serve_blocks(fitted_engine, blocks, "buffered", obs)
+    assert obs.metrics.snapshot().get(site, 0) == 0
 
 
 def test_pipeline_params_requires_fused_model():

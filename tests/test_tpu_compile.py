@@ -20,7 +20,11 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.features import feature_dim
 from repro.kernels.estimator_mlp.ops import _estimator_mlp_pallas
 from repro.kernels.iou_matrix.ops import _iou_matrix_batch
-from repro.kernels.score_pipeline.ops import _score_pipeline_pallas
+from repro.kernels.score_pipeline.ops import (
+    PACKED_SLOT_BYTES,
+    _score_packed,
+    _score_pipeline_pallas,
+)
 from repro.video import track as track_mod
 
 HIDDEN = 128
@@ -92,6 +96,27 @@ def test_fused_score_kernel_compiles(one_chip, no_compile_cache, num_classes, to
     compiled = _score_pipeline_pallas.lower(
         *args, num_classes=num_classes, top_k=top_k, tile_b=ENGINE_TILE_B,
         interpret=False,
+    ).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+def test_packed_score_entry_compiles(one_chip, no_compile_cache, batch):
+    """The packed host-block entry (one ``uint8`` buffer unpacked on the
+    device, then the fused kernel) at the COCO head: 80 classes, 100
+    detection slots, top-100, a ragged and a full ``micro_batch`` chunk."""
+    f32 = jnp.float32
+    num_classes, top_k, slots = 80, 100, 100
+    F = feature_dim(num_classes, top_k)
+    args = _shapes(
+        one_chip,
+        ((batch, PACKED_SLOT_BYTES * slots), jnp.uint8),
+        ((F, HIDDEN), f32), ((HIDDEN,), f32), ((HIDDEN,), f32), ((), f32),
+        ((F,), f32), ((F,), f32), ((), f32),
+    )
+    compiled = _score_packed.lower(
+        *args, num_classes=num_classes, top_k=top_k, tile_b=ENGINE_TILE_B,
+        path="pallas",
     ).compile()
     _assert_kernel(compiled)
 
