@@ -267,3 +267,9 @@ class Served:
         mismatch = int(np.sum(want_off != O))
         return [("logit_gap", gap, float(cfg["limits"]["logit_gap"])),
                 ("decision_mismatch", float(mismatch), 0.0)]
+
+    def check_control(self) -> List[Tuple[str, float, float]]:
+        """The check, with the control put in the program's place: the
+        reference in bfloat16 (``reference/control.py``) on the same rows."""
+        from reference import control
+        return self.check(estimates=control.estimates)

@@ -246,6 +246,12 @@ class Served:
                 ("decision_mismatch", float(mismatch), 0.0),
                 ("unscored_sampled", float(np.sum(~ok)), 0.0)]
 
+    def check_control(self) -> List[Tuple[str, float, float]]:
+        """The check, with the control put in the program's place: the
+        reference in bfloat16 (``reference/control.py``) on the same rows."""
+        from reference import control
+        return self.check(estimates=control.estimates)
+
 
 def reference_forward(rows: Dict, params: Dict, cfg: Dict, block: int = 512) -> np.ndarray:
     """Reference estimates in float64, in blocks of rows."""

@@ -6,10 +6,14 @@ many seeds and the control's on the same rows.
 
 Each seed runs the cell as ``run.py`` does (a short window at the cell's
 own load, at its own sizes) and prints the compared numbers; with
-``--control-seeds`` of the seeds the control (``reference/control.py``:
-the reference in bfloat16) is then put in the program's place on the same
-sampled rows and goes through the same check, which has to find it not
-correct.  All seeds run in one process, so only the first compiles.
+``--control-seeds`` of the seeds the entry's control is then put in the
+program's place on the same sampled rows and goes through the same check
+(``Served.check_control``), which has to find it not correct.  All seeds
+run in one process, so only the first compiles.
+
+The summary gives, for each number the entry's check compares, the
+program's largest reading and all its readings sorted, the control's
+smallest reading and the limit.
 """
 from __future__ import annotations
 
@@ -20,7 +24,6 @@ import time
 
 import run  # noqa: E402  (puts the harness and the program on the path)
 from core import device  # noqa: E402
-from reference import control  # noqa: E402
 
 
 def main() -> int:
@@ -50,23 +53,23 @@ def main() -> int:
                "max_abs_estimate_gap": served.est_gap,
                "metrics": {k: v["value"] for k, v in result["metrics"].items()}}
         if i < args.control_seeds:
-            ctrl = served.check(estimates=control.estimates)
+            ctrl = served.check_control()
             row["control"] = {n: v for n, v, _ in ctrl}
             row["control"]["max_abs_estimate_gap"] = served.est_gap
             row["control_correct"] = all(v <= lim for _, v, lim in ctrl)
         row["wall_s"] = time.time() - t
         rows_out.append(row)
         print("reading " + json.dumps(row), flush=True)
-    prog = [r["program"]["logit_gap"] for r in rows_out]
-    ctrl = [r["control"]["logit_gap"] for r in rows_out if "control" in r]
-    print("summary " + json.dumps({
-        "workload": args.workload, "program_logit_gap_max": max(prog),
-        "program_logit_gap_sorted": sorted(prog),
-        "control_logit_gap_min": min(ctrl) if ctrl else None,
-        "decision_mismatch_max": max(r["program"]["decision_mismatch"] for r in rows_out),
-        "all_correct": all(r["correct"] for r in rows_out),
-        "control_ever_correct": any(r.get("control_correct", False) for r in rows_out)}),
-        flush=True)
+    summary = {"workload": args.workload}
+    for name, _, limit in checks:
+        prog = [r["program"][name] for r in rows_out]
+        ctrl = [r["control"][name] for r in rows_out if "control" in r]
+        summary.update({f"program_{name}_max": max(prog), f"program_{name}_sorted": sorted(prog),
+                        f"control_{name}_min": min(ctrl) if ctrl else None,
+                        f"limit_{name}": limit})
+    summary["all_correct"] = all(r["correct"] for r in rows_out)
+    summary["control_ever_correct"] = any(r.get("control_correct", False) for r in rows_out)
+    print("summary " + json.dumps(summary), flush=True)
     return 0
 
 
