@@ -102,6 +102,41 @@ class DetectionBoxFeatures:
         }
 
 
+def _logit_feature_sums(logits: jnp.ndarray, vmask: jnp.ndarray, top_k: int):
+    """Per-row sums over the positions ``vmask`` keeps of entropy, margin,
+    top-1 and the top-k probs, and the masked max entropy, from (B, S, V)
+    logits in float32."""
+    lf = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    entropy = -(jnp.exp(lf) * lf).sum(-1)  # (B,S)
+    # (B,S,k), the k largest probs; top_k over 2-D rows (a TPU lowers a
+    # 3-D top_k over a wide vocabulary to a full sort)
+    V = lf.shape[-1]
+    topv = jnp.exp(jax.lax.top_k(lf.reshape(-1, V), top_k)[0]).reshape(lf.shape[:-1] + (top_k,))
+    v = vmask.astype(jnp.float32)
+    return (
+        (entropy * v).sum(-1),
+        jnp.max(entropy * v, axis=-1),
+        ((topv[..., 0] - topv[..., 1]) * v).sum(-1),
+        (topv[..., 0] * v).sum(-1),
+        (topv * v[..., None]).sum(1),
+        v.sum(-1),
+    )
+
+
+def _features_of_sums(ent, ent_max, margin, top1, topk, count) -> jnp.ndarray:
+    denom = jnp.maximum(count, 1.0)
+    return jnp.concatenate(
+        [
+            (ent / denom)[:, None],
+            ent_max[:, None],
+            (margin / denom)[:, None],
+            (top1 / denom)[:, None],
+            topk / denom[:, None],  # mean top-k probs
+        ],
+        axis=-1,
+    )
+
+
 def logits_features(
     logits: jnp.ndarray, labels: Optional[jnp.ndarray] = None, top_k: int = 8
 ) -> np.ndarray:
@@ -111,30 +146,33 @@ def logits_features(
     ``labels`` marks valid positions (>= 0); ``None`` treats every position
     as valid (the decode-time case where no gold labels exist).
     """
-    lf = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    p = jnp.exp(lf)
-    if labels is None:
-        labels = jnp.zeros(logits.shape[:-1], jnp.int32)
-    entropy = -(p * lf).sum(-1)  # (B,S)
-    topv, _ = jax.lax.top_k(p, top_k)  # (B,S,k)
-    margin = topv[..., 0] - topv[..., 1]
-    vmask = labels >= 0
-    denom = jnp.maximum(vmask.sum(-1), 1)
+    vmask = jnp.ones(logits.shape[:-1], bool) if labels is None else labels >= 0
+    return np.asarray(_features_of_sums(*_logit_feature_sums(logits, vmask, top_k)))
 
-    def mavg(x):
-        return (x * vmask).sum(-1) / denom
 
-    feats = jnp.concatenate(
-        [
-            mavg(entropy)[:, None],
-            jnp.max(entropy * vmask, axis=-1)[:, None],
-            mavg(margin)[:, None],
-            mavg(topv[..., 0])[:, None],
-            (topv * vmask[..., None]).sum(1) / denom[:, None],  # mean top-k probs
-        ],
-        axis=-1,
+def chunked_logits_features(
+    head: Callable[[jnp.ndarray], jnp.ndarray],
+    h: jnp.ndarray,
+    vmask: jnp.ndarray,
+    top_k: int = 8,
+    chunk: int = 512,
+) -> jnp.ndarray:
+    """``logits_features`` of ``head(h)`` without its (B, S, V) logits:
+    the positions of the (B, S, M) hidden states go through ``head``
+    ((B, c, M) -> (B, c, V) float32 logits) ``chunk`` at a time and only
+    per-row sums leave each chunk.  ``vmask`` (B, S) marks the positions
+    that count.  Returns a device (B, 4 + top_k) array."""
+    B, S, M = h.shape
+    c = chunk if 0 < chunk < S and S % chunk == 0 else S
+    n = S // c
+    parts = jax.lax.map(
+        lambda a: _logit_feature_sums(head(a[0]), a[1], top_k),
+        (jnp.moveaxis(h.reshape(B, n, c, M), 1, 0), jnp.moveaxis(vmask.reshape(B, n, c), 1, 0)),
     )
-    return np.asarray(feats)
+    ent, ent_max, margin, top1, topk, count = parts
+    return _features_of_sums(
+        ent.sum(0), ent_max.max(0), margin.sum(0), top1.sum(0), topk.sum(0), count.sum(0)
+    )
 
 
 @register_feature_extractor("lm_logits")

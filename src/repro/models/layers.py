@@ -6,14 +6,15 @@ Everything is functional: ``*_init(key, ...) -> params`` (nested dicts) and
 dry-run).  All sequence stacks use ``jax.lax.scan``-compatible shapes so a
 64-layer model lowers to depth-independent HLO.
 
-Covered: RMSNorm/LayerNorm, RoPE + M-RoPE, GQA attention (bias, qk_norm,
-sliding window, KV-cache decode), SwiGLU/GELU MLP, capacity-based MoE
-(shared + routed top-k, load-balance aux), MLA (compressed KV), RWKV6
-time/channel mix, Mamba2 (SSD) block.
+Covered: RMSNorm/LayerNorm, RoPE + M-RoPE + YaRN, GQA attention (bias,
+qk_norm, sliding window, KV-cache decode), SwiGLU/GELU MLP, MoE (shared +
+routed top-k, load-balance aux; capacity-based or dropless), MLA
+(compressed KV), RWKV6 time/channel mix, Mamba2 (SSD) block.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -90,10 +91,37 @@ def rope_freqs(head_dim: int, theta: float = 1e6) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float = 1e6) -> jnp.ndarray:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor ``0.1·m·ln(s) + 1`` (1 for s <= 1)."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(
+    dim: int, theta: float, factor: float, original_max: int,
+    beta_fast: float, beta_slow: float,
+) -> jnp.ndarray:
+    """YaRN rotary frequencies as DeepSeek-V2 defines them: the dims whose
+    wavelength fits ``beta_fast`` times into ``original_max`` keep their
+    frequency, those fitting fewer than ``beta_slow`` times are divided by
+    ``factor``, and a linear ramp over ``[low, high]`` blends between."""
+    def dim_of(rotations: float) -> float:
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), dim - 1)
+    extra = rope_freqs(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0, 1)
+    return extra / factor * ramp + extra * (1 - ramp)
+
+
+def apply_rope(
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float = 1e6,
+    freqs: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).  ``freqs``
+    (D/2,) replaces the plain ``theta`` frequencies (YaRN)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # (D/2,)
+    freqs = rope_freqs(d, theta) if freqs is None else freqs  # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., S, 1, D/2)
     sin = jnp.sin(angles)[..., None, :]
@@ -410,6 +438,8 @@ class MoEConfig(NamedTuple):
     capacity_factor: float = 1.25
     aux_weight: float = 0.001
     groups: int = 0  # >0: group-local dispatch (see moe_apply_grouped)
+    norm_topk: bool = True  # renormalise the k gates to sum to 1
+    dropless: bool = False  # sorted ragged dispatch (moe_apply_dropless)
 
 
 def moe_init(key, cfg: MoEConfig, dtype=jnp.float32) -> PyTree:
@@ -427,13 +457,92 @@ def moe_init(key, cfg: MoEConfig, dtype=jnp.float32) -> PyTree:
 
 
 def moe_apply(
-    params: PyTree, cfg: MoEConfig, x: jnp.ndarray
+    params: PyTree, cfg: MoEConfig, x: jnp.ndarray, valid: Optional[jnp.ndarray] = None
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Dispatches to the grouped (SPMD-shardable) path when cfg.groups > 0
-    and the token count divides; otherwise the flat-capacity path below."""
+    """Dispatches to the dropless path when ``cfg.dropless``; else to the
+    grouped (SPMD-shardable) path when cfg.groups > 0 and the token count
+    divides; otherwise the flat-capacity path below.  ``valid`` (B, S)
+    marks the tokens the dropless path routes (pads are left out); the
+    capacity paths route every token."""
+    if cfg.dropless:
+        return moe_apply_dropless(params, cfg, x, valid)
     if cfg.groups and (x.shape[0] * x.shape[1]) % cfg.groups == 0:
         return moe_apply_grouped(params, cfg, x)
     return moe_apply_flat(params, cfg, x)
+
+
+def _topk_gates(probs: jnp.ndarray, cfg: MoEConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Greedy top-k of the router's softmax: the k gates (renormalised to
+    sum to 1 when ``cfg.norm_topk``) and the expert ids, in float32."""
+    gate, ids = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk:
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    return gate, ids
+
+
+def _route(params: PyTree, cfg: MoEConfig, tok: jnp.ndarray):
+    """float32 router over (T, M) tokens: (probs (T, E), gates, ids (T, k))."""
+    probs = jax.nn.softmax(tok.astype(jnp.float32) @ params["router"].astype(jnp.float32), -1)
+    return (probs,) + _topk_gates(probs, cfg)
+
+
+def _switch_aux(probs: jnp.ndarray, ids: jnp.ndarray, cfg: MoEConfig) -> jnp.ndarray:
+    """Switch load-balance loss ``E·Σ_e f_e·p̄_e`` over (T, E) probs."""
+    E = cfg.num_experts
+    f = jnp.mean((jax.nn.one_hot(ids, E).sum(axis=1) > 0).astype(jnp.float32), axis=0)
+    return cfg.aux_weight * E * jnp.sum(f * probs.mean(axis=0))
+
+
+def _sorted_pairs(ids: jnp.ndarray, valid: Optional[jnp.ndarray], E: int):
+    """(token, expert) pairs sorted by expert: the sort order over the
+    flat (T·k,) pairs and the pair count of each expert.  Pairs of
+    invalid tokens take the id ``E``, sort last and fall in no group."""
+    flat_e = ids.reshape(-1)
+    if valid is not None:
+        flat_e = jnp.where(jnp.repeat(valid.reshape(-1), ids.shape[1]), flat_e, E)
+    order = jnp.argsort(flat_e, stable=True)
+    # a one-hot count, not a scatter-add: 64 bins take every pair
+    sizes = (flat_e[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
+    return order, sizes
+
+
+def moe_load(
+    params: PyTree, cfg: MoEConfig, x: jnp.ndarray, valid: Optional[jnp.ndarray] = None
+) -> jnp.ndarray:
+    """(E,) routed (token, expert) pairs per expert over the valid tokens
+    of ``x`` (B, S, M), as the router of ``moe_apply`` picks them."""
+    _, _, ids = _route(params, cfg, x.reshape(-1, x.shape[-1]))
+    return _sorted_pairs(ids, valid, cfg.num_experts)[1]
+
+
+def moe_apply_dropless(
+    params: PyTree, cfg: MoEConfig, x: jnp.ndarray, valid: Optional[jnp.ndarray] = None
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x: (B, S, M) -> (out, aux_loss) with no capacity: every (token,
+    expert) pair the router picks is computed.  The pairs are sorted by
+    expert and the three expert matmuls run as ``jax.lax.ragged_dot`` over
+    the sorted rows, one group per expert; the outputs are put back in
+    pair order and summed with their gates.  Tokens where ``valid`` is
+    False fall in no group, so pads cost no expert work and get no routed
+    output (the shared experts still see them)."""
+    B, S, M = x.shape
+    T, K = B * S, cfg.top_k
+    tok = x.reshape(T, M)
+    probs, gate, ids = _route(params, cfg, tok)
+    order, sizes = _sorted_pairs(ids, valid, cfg.num_experts)
+    rows = tok[order // K]  # (T·k, M), grouped by expert
+    dt = x.dtype
+    g = jax.nn.silu(jax.lax.ragged_dot(rows, params["w_gate"].astype(dt), sizes))
+    u = jax.lax.ragged_dot(rows, params["w_up"].astype(dt), sizes)
+    y = jax.lax.ragged_dot(g * u, params["w_down"].astype(dt), sizes)
+    back = jnp.argsort(order)  # the inverse permutation
+    y = y[back].reshape(T, K, M).astype(jnp.float32)
+    if valid is not None:  # rows outside every group: zero them whatever they hold
+        y = jnp.where(valid.reshape(T, 1, 1), y, 0.0)
+    out = jnp.einsum("tkm,tk->tm", y, gate).astype(dt)
+    if cfg.num_shared and "shared" in params:
+        out = out + swiglu(params["shared"], tok)
+    return out.reshape(B, S, M), _switch_aux(probs, ids, cfg)
 
 
 def moe_apply_flat(
@@ -455,10 +564,8 @@ def moe_apply_flat(
     E, K = cfg.num_experts, cfg.top_k
     cap = int((T * K / E) * cfg.capacity_factor) + 1
     tok = x.reshape(T, M)
-    logits = tok.astype(jnp.float32) @ params["router"]  # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, expert_ids = jax.lax.top_k(probs, K)  # (T, K)
-    gate = (gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)).astype(x.dtype)
+    probs, gate, expert_ids = _route(params, cfg, tok)  # (T, E), (T, K), (T, K)
+    gate = gate.astype(x.dtype)
 
     flat_e = expert_ids.reshape(-1)  # (T*K,)
     onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (T*K, E)
@@ -487,13 +594,7 @@ def moe_apply_flat(
     out = out_tok.reshape(T, K, M).sum(axis=1)
     if cfg.num_shared and "shared" in params:
         out = out + swiglu(params["shared"], tok)
-    # load-balance aux loss (Switch): E * Σ_e f_e·p̄_e
-    f = jnp.mean(
-        (jax.nn.one_hot(expert_ids, E).sum(axis=1) > 0).astype(jnp.float32), axis=0
-    )
-    pbar = probs.mean(axis=0)
-    aux = cfg.aux_weight * E * jnp.sum(f * pbar)
-    return out.reshape(B, S, M), aux
+    return out.reshape(B, S, M), _switch_aux(probs, expert_ids, cfg)
 
 
 def moe_apply_grouped(
@@ -523,8 +624,8 @@ def moe_apply_grouped(
     tok_f32 = constrain(tok.astype(jnp.float32), "batch", None, None)
     logits = tok_f32 @ params["router"].astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)  # (G, Tg, E)
-    gate, expert_ids = jax.lax.top_k(probs, K)  # (G, Tg, K)
-    gate = (gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)).astype(x.dtype)
+    gate, expert_ids = _topk_gates(probs, cfg)  # (G, Tg, K)
+    gate = gate.astype(x.dtype)
 
     flat_e = expert_ids.reshape(G, Tg * K)
     onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)  # (G, TgK, E)
@@ -576,13 +677,7 @@ def moe_apply_grouped(
     out = constrain(out, "batch", None, None).reshape(B, S, M)
     if cfg.num_shared and "shared" in params:
         out = out + swiglu(params["shared"], tok.reshape(T, M)).reshape(B, S, M)
-    f = jnp.mean(
-        (jax.nn.one_hot(expert_ids, E).sum(axis=2) > 0).astype(jnp.float32),
-        axis=(0, 1),
-    )
-    pbar = probs.mean(axis=(0, 1))
-    aux = cfg.aux_weight * E * jnp.sum(f * pbar)
-    return out, aux
+    return out, _switch_aux(probs.reshape(T, E), expert_ids.reshape(T, K), cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -598,6 +693,29 @@ class MLAConfig(NamedTuple):
     v_dim: int = 128
     rope_theta: float = 1e6
     attn_chunk: int = 0  # query chunking, as in AttnConfig
+    # YaRN (DeepSeek-V2's ``rope_scaling``): (factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim); None = plain RoPE
+    rope_scaling: Optional[Tuple[float, int, float, float, float, float]] = None
+
+
+def mla_rope(cfg: MLAConfig) -> Tuple[Optional[jnp.ndarray], float, float]:
+    """``(inv_freq, rope_mscale, softmax_scale)`` of MLA's rotary dims.
+    Plain RoPE: ``(None, 1, (qk_nope + qk_rope)^-0.5)``.  YaRN: the YaRN
+    frequencies, cos/sin scaled by ``mscale(f, mscale) / mscale(f,
+    mscale_all_dim)`` and the softmax scale by ``mscale(f, mscale_all_dim)²``."""
+    base = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    if cfg.rope_scaling is None:
+        return None, 1.0, base
+    factor, orig, beta_fast, beta_slow, mscale, mscale_all = cfg.rope_scaling
+    inv = yarn_inv_freq(cfg.qk_rope_dim, cfg.rope_theta, factor, orig, beta_fast, beta_slow)
+    rope_m = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
+    return inv, rope_m, base * yarn_mscale(factor, mscale_all) ** 2
+
+
+def _mla_rotate(x, positions, cfg: MLAConfig, inv, rope_m):
+    out = apply_rope(x, positions, cfg.rope_theta, freqs=inv)
+    return out if rope_m == 1.0 else out * jnp.asarray(rope_m, out.dtype)
 
 
 def mla_init(key, cfg: MLAConfig, dtype=jnp.float32) -> PyTree:
@@ -619,29 +737,31 @@ def mla_apply(
     params: PyTree, cfg: MLAConfig, x: jnp.ndarray, positions: jnp.ndarray,
     return_kv: bool = False,
 ):
-    """Training/prefill: expanded-KV form (matches reference semantics)."""
+    """Training/prefill: expanded-KV form (matches reference semantics).
+    Scores and the softmax are float32."""
     B, S, M = x.shape
     H, N, P, V = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_dim
+    inv, rope_m, scale = mla_rope(cfg)
     q = (x @ params["wq"].astype(x.dtype)).reshape(B, S, H, N + P)
     q_nope, q_rope = q[..., :N], q[..., N:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rotate(q_rope, positions, cfg, inv, rope_m)
     c_kv = rmsnorm(params["kv_norm"], x @ params["w_dkv"].astype(x.dtype))  # (B,S,R)
-    k_rope = apply_rope(
-        (x @ params["w_kr"].astype(x.dtype))[:, :, None, :], positions, cfg.rope_theta
+    k_rope = _mla_rotate(
+        (x @ params["w_kr"].astype(x.dtype))[:, :, None, :], positions, cfg, inv, rope_m
     )  # (B,S,1,P) shared across heads
     k_nope = (c_kv @ params["w_uk"].astype(x.dtype)).reshape(B, S, H, N)
     v = (c_kv @ params["w_uv"].astype(x.dtype)).reshape(B, S, H, V)
-    scale = 1.0 / jnp.sqrt(N + P).astype(x.dtype)
     mask = causal_mask(S, S, 0)  # (1, S, T)
+    f32 = jnp.float32
 
     def _attend(qn, qr, m):
         # qn: (B, s, H, N), qr: (B, s, H, P), m: (1, s, T)
         logits = (
-            jnp.einsum("bshn,bthn->bhst", qn, k_nope)
-            + jnp.einsum("bshp,btp->bhst", qr, k_rope[:, :, 0])
+            jnp.einsum("bshn,bthn->bhst", qn, k_nope, preferred_element_type=f32)
+            + jnp.einsum("bshp,btp->bhst", qr, k_rope[:, :, 0], preferred_element_type=f32)
         ) * scale
-        logits = jnp.where(m[:, None], logits, jnp.finfo(logits.dtype).min)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(x.dtype)
+        logits = jnp.where(m[:, None], logits, jnp.finfo(f32).min)
+        probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
         s_len = qn.shape[1]
         return jnp.einsum("bhst,bthv->bshv", probs, v).reshape(B, s_len, H * V)
 
@@ -667,36 +787,40 @@ def mla_decode(
     x: jnp.ndarray,  # (B, 1, M)
     cache_c: jnp.ndarray,  # (B, C, R)   compressed latent cache
     cache_kr: jnp.ndarray,  # (B, C, P)  shared rope-key cache
-    pos: jnp.ndarray,
+    pos: jnp.ndarray,  # () or (B,) int32 — position of this token, per row
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Decode with the **absorbed** form: the cache stores only the R-dim
     latent + P-dim rope key per token (the paper-headline KV saving);
     W_uk is absorbed into the query, W_uv into the output projection.
+    Each row writes its slot ``pos[b]`` and attends to slots ``<= pos[b]``,
+    so rows of a block may sit at different positions (ragged prompts).
     """
     B, _, M = x.shape
     H, N, P, V, R = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_dim, cfg.kv_lora_rank
+    inv, rope_m, scale = mla_rope(cfg)
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     q = (x @ params["wq"].astype(x.dtype)).reshape(B, 1, H, N + P)
     q_nope, q_rope = q[..., :N], q[..., N:]
-    q_rope = apply_rope(q_rope, jnp.full((B, 1), pos), cfg.rope_theta)
+    q_rope = _mla_rotate(q_rope, pos[:, None], cfg, inv, rope_m)
     c_new = rmsnorm(params["kv_norm"], x @ params["w_dkv"].astype(x.dtype))  # (B,1,R)
-    kr_new = apply_rope(
-        (x @ params["w_kr"].astype(x.dtype))[:, :, None, :], jnp.full((B, 1), pos),
-        cfg.rope_theta,
+    kr_new = _mla_rotate(
+        (x @ params["w_kr"].astype(x.dtype))[:, :, None, :], pos[:, None], cfg, inv, rope_m
     )[:, :, 0]  # (B,1,P)
-    cache_c = jax.lax.dynamic_update_slice_in_dim(cache_c, c_new, pos, axis=1)
-    cache_kr = jax.lax.dynamic_update_slice_in_dim(cache_kr, kr_new, pos, axis=1)
+    rows = jnp.arange(B)
+    cache_c = cache_c.at[rows, pos].set(c_new[:, 0])
+    cache_kr = cache_kr.at[rows, pos].set(kr_new[:, 0])
     # absorb W_uk into q: q_lat (B,H,R)
     w_uk = params["w_uk"].astype(x.dtype).reshape(R, H, N)
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)
-    scale = 1.0 / jnp.sqrt(N + P).astype(x.dtype)
+    f32 = jnp.float32
     logits = (
-        jnp.einsum("bhr,bcr->bhc", q_lat, cache_c)
-        + jnp.einsum("bhp,bcp->bhc", q_rope[:, 0], cache_kr)
+        jnp.einsum("bhr,bcr->bhc", q_lat, cache_c, preferred_element_type=f32)
+        + jnp.einsum("bhp,bcp->bhc", q_rope[:, 0], cache_kr, preferred_element_type=f32)
     ) * scale
     C = cache_c.shape[1]
-    valid = (jnp.arange(C) <= pos)[None, None, :]
-    logits = jnp.where(valid, logits, jnp.finfo(logits.dtype).min)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(x.dtype)
+    valid = (jnp.arange(C)[None, :] <= pos[:, None])[:, None, :]  # (B, 1, C)
+    logits = jnp.where(valid, logits, jnp.finfo(f32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     ctx = jnp.einsum("bhc,bcr->bhr", probs, cache_c)  # attend in latent space
     w_uv = params["w_uv"].astype(x.dtype).reshape(R, H, V)
     out = jnp.einsum("bhr,rhv->bhv", ctx, w_uv).reshape(B, 1, H * V)

@@ -11,10 +11,17 @@ API (all functional):
   init_params(cfg, key)       real params (smoke scale)
   abstract_params(cfg)        ShapeDtypeStruct pytree (dry-run, no alloc)
   forward(params, cfg, batch) logits (B, S, V) — train/prefill path
+  forward_hidden(params, cfg, batch) final hidden state (B, S, M) before the head
   loss_fn(params, cfg, batch) scalar CE (+ MoE aux)
   init_cache(cfg, B, capacity[, abstract]) decode cache pytree
   prefill(params, cfg, batch, capacity) -> (last_logits, cache)
   decode_step(params, cfg, cache, tokens, pos) -> (logits, cache)
+
+Ragged rows (MLA stacks): ``batch["lengths"]`` (B,) marks each row's
+prompt as its first ``lengths[b]`` tokens, right-padded.  Attention is
+causal, so the pads never reach a real position; they are left out of the
+routed experts, ``prefill`` reads each row's logits at its own last token,
+and ``decode_step`` takes a per-row ``pos`` (B,) for the next slot.
 """
 from __future__ import annotations
 
@@ -48,6 +55,7 @@ from repro.models.layers import (
     mla_init,
     moe_apply,
     moe_init,
+    moe_load,
     rmsnorm,
     rmsnorm_init,
     rwkv6_channel_mix,
@@ -97,11 +105,16 @@ class LMConfig:
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
     moe_groups: int = 0  # >0: group-local dispatch (§Perf hillclimb #1)
+    moe_dropless: bool = False  # sorted ragged dispatch, no capacity
+    norm_topk_prob: bool = True  # renormalise the top-k gates
     # MLA (deepseek-v2)
     use_mla: bool = False
     kv_lora_rank: int = 512
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
+    # YaRN rope_scaling of MLA: (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, mscale, mscale_all_dim); None = plain RoPE
+    rope_scaling: Optional[Tuple[float, int, float, float, float, float]] = None
     # RWKV6
     rwkv_head_size: int = 64
     # hybrid (zamba2)
@@ -154,6 +167,8 @@ class LMConfig:
             num_shared=self.num_shared_experts,
             capacity_factor=self.capacity_factor,
             groups=self.moe_groups,
+            norm_topk=self.norm_topk_prob,
+            dropless=self.moe_dropless,
         )
 
     def mla(self) -> MLAConfig:
@@ -166,6 +181,7 @@ class LMConfig:
             v_dim=self.head_dim,
             rope_theta=self.rope_theta,
             attn_chunk=self.attn_chunk,
+            rope_scaling=self.rope_scaling,
         )
 
     def rwkv(self) -> RWKV6Config:
@@ -351,6 +367,50 @@ def _positions(batch: Dict, B: int, S: int) -> jnp.ndarray:
     return jnp.broadcast_to(jnp.arange(S)[None], (B, S))
 
 
+def takes_ragged_rows(cfg: LMConfig) -> bool:
+    """Whether the stack takes ragged rows (``lengths``, per-row ``pos``)."""
+    return cfg.arch_type == "moe" and cfg.use_mla
+
+
+def _valid(cfg: LMConfig, batch: Dict, S: int) -> Optional[jnp.ndarray]:
+    """(B, S) real-token mask of a ragged batch (``lengths``), else None."""
+    if "lengths" not in batch:
+        return None
+    if not takes_ragged_rows(cfg):
+        raise ValueError(f"ragged rows (lengths) need an MLA stack, not {cfg.arch_type!r}")
+    return jnp.arange(S)[None, :] < jnp.asarray(batch["lengths"])[:, None]
+
+
+def _over_layers(cfg: LMConfig, body, carry, layers, *per_layer):
+    """``body(carry, (layer, *per_layer[i]))`` over a layer stack, with each
+    of ``per_layer`` (stacked on axis 0) sliced alongside.  A stacked pytree
+    is scanned; a list of per-layer pytrees (``unstack_layers``: each layer
+    its own buffers, as serving keeps them) is looped, so a kernel's operands
+    are whole buffers and not slices of a stack copied on every call.
+    Returns ``(carry, ys)`` with ``ys`` stacked on axis 0 as a scan gives."""
+    if not isinstance(layers, (list, tuple)):
+        xs = (layers,) + per_layer if per_layer else layers
+        return _scan(cfg)(body, carry, xs)
+    if not layers:
+        raise ValueError("an empty list of layers")
+    ys = []
+    for i, lp in enumerate(layers):
+        carry, y = body(carry, (lp,) + tuple(a[i] for a in per_layer) if per_layer else lp)
+        ys.append(y)
+    return carry, jax.tree.map(lambda *a: jnp.stack(a), *ys)
+
+
+def unstack_layers(params: PyTree) -> PyTree:
+    """``params`` with each stacked layer group (``layers``, ``dense_layers``,
+    ``moe_layers``) as a list of per-layer pytrees, each its own buffers."""
+    out = dict(params)
+    for k in ("layers", "dense_layers", "moe_layers"):
+        if k in params and not isinstance(params[k], (list, tuple)):
+            n = jax.tree.leaves(params[k])[0].shape[0]
+            out[k] = [jax.tree.map(lambda a: a[i], params[k]) for i in range(n)]
+    return out
+
+
 def _maybe_remat(fn, cfg: LMConfig):
     return jax.checkpoint(fn) if cfg.remat else fn
 
@@ -364,7 +424,10 @@ def _embed(params, cfg: LMConfig, batch) -> jnp.ndarray:
     return constrain(emb, "batch", None, None)
 
 
-def _logits(params, cfg: LMConfig, h) -> jnp.ndarray:
+def _logits(params, cfg: LMConfig, h, f32: bool = False) -> jnp.ndarray:
+    """The head over (B, S, M) hidden states; ``f32`` accumulates the
+    logits into float32 (serving's few positions) instead of the
+    activation dtype (training's every position)."""
     h = (
         layernorm(params["final_norm"], h)
         if cfg.arch_type in ("rwkv", "encdec")
@@ -377,8 +440,13 @@ def _logits(params, cfg: LMConfig, h) -> jnp.ndarray:
         w = constrain(params["embed"].astype(h.dtype).T, None, "model")
     else:
         w = params["unembed"].astype(h.dtype)
-    logits = h @ w
+    logits = jnp.matmul(h, w, preferred_element_type=jnp.float32 if f32 else None)
     return constrain(logits, "batch", None, "model")
+
+
+def lm_head(params: PyTree, cfg: LMConfig, h: jnp.ndarray) -> jnp.ndarray:
+    """float32 logits of (B, S, M) final hidden states (``forward_hidden``)."""
+    return _logits(params, cfg, h, f32=True)
 
 
 def _dense_block(lp, cfg: LMConfig, h, positions, positions_3d):
@@ -393,14 +461,16 @@ def _dense_block(lp, cfg: LMConfig, h, positions, positions_3d):
     return h
 
 
-def _moe_block(lp, cfg: LMConfig, h, positions, aux):
+def _moe_block(lp, cfg: LMConfig, h, positions, aux, valid=None):
+    """One MoE layer: (h, aux + its aux loss, its (E,) expert load)."""
     if cfg.use_mla:
         a = mla_apply(lp["attn"], cfg.mla(), rmsnorm(lp["norm1"], h), positions)
     else:
         a = attention_apply(lp["attn"], cfg.attn(), rmsnorm(lp["norm1"], h), positions)
     h = h + constrain(a, "batch", None, None)
-    out, aux_l = moe_apply(lp["moe"], cfg.moe(), rmsnorm(lp["norm2"], h))
-    return h + out, aux + aux_l
+    hn = rmsnorm(lp["norm2"], h)
+    out, aux_l = moe_apply(lp["moe"], cfg.moe(), hn, valid)
+    return h + out, aux + aux_l, moe_load(lp["moe"], cfg.moe(), hn, valid)
 
 
 def _rwkv_block(lp, cfg: LMConfig, h, state, x_tm, x_cm):
@@ -429,11 +499,22 @@ def _shared_attn_block(sp, cfg: LMConfig, h, positions):
 
 def forward(params: PyTree, cfg: LMConfig, batch: Dict) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Full-sequence forward.  Returns (logits, moe_aux)."""
+    h, aux, _ = forward_hidden(params, cfg, batch)
+    return _logits(params, cfg, h), aux
+
+
+def forward_hidden(params: PyTree, cfg: LMConfig, batch: Dict):
+    """Full-sequence forward up to the head.  Returns (final hidden state
+    (B, S, M) before the final norm, moe_aux, loads): ``loads`` is the
+    (num MoE layers, E) routed pairs per expert over the real tokens, or
+    None for a stack without experts."""
     h = _embed(params, cfg, batch)
     B, S, _ = h.shape
     positions = _positions(batch, B, S)
     positions_3d = batch.get("positions_3d")
+    valid = _valid(cfg, batch, S)
     aux = jnp.zeros((), jnp.float32)
+    loads = None
     at = cfg.arch_type
 
     if at in ("dense", "vlm"):
@@ -447,14 +528,16 @@ def forward(params: PyTree, cfg: LMConfig, batch: Dict) -> Tuple[jnp.ndarray, jn
             body_d = _maybe_remat(
                 lambda hh, lp: (_dense_block(lp, cfg, hh, positions, None), None), cfg
             )
-            h, _ = _scan(cfg)(body_d, h, params["dense_layers"])
+            h, _ = _over_layers(cfg, body_d, h, params["dense_layers"])
 
         def moe_body(carry, lp):
             hh, ax = carry
-            hh, ax = _moe_block(lp, cfg, hh, positions, ax)
-            return (hh, ax), None
+            hh, ax, load = _moe_block(lp, cfg, hh, positions, ax, valid)
+            return (hh, ax), load
 
-        (h, aux), _ = _scan(cfg)(_maybe_remat(moe_body, cfg), (h, aux), params["moe_layers"])
+        (h, aux), loads = _over_layers(
+            cfg, _maybe_remat(moe_body, cfg), (h, aux), params["moe_layers"]
+        )
     elif at == "rwkv":
         def rwkv_body(hh, lp):
             hh, _, _, _ = _rwkv_block(lp, cfg, hh, None, None, None)
@@ -479,7 +562,7 @@ def forward(params: PyTree, cfg: LMConfig, batch: Dict) -> Tuple[jnp.ndarray, jn
         h = _decode_stack(params, cfg, h, enc, positions)
     else:
         raise ValueError(at)
-    return _logits(params, cfg, h), aux
+    return h, aux, loads
 
 
 def _encode(params, cfg: LMConfig, batch) -> jnp.ndarray:
@@ -643,7 +726,7 @@ def decode_step(
     cfg: LMConfig,
     cache: PyTree,
     tokens: jnp.ndarray,  # (B,) next input token ids
-    pos: jnp.ndarray,  # () int32 current position
+    pos: jnp.ndarray,  # () int32 current position; (B,) per row on MLA stacks
     positions_3d: Optional[jnp.ndarray] = None,  # (3, B, 1) for vlm
 ) -> Tuple[jnp.ndarray, PyTree]:
     """One-token decode; returns (logits (B, V), new cache)."""
@@ -711,15 +794,15 @@ def decode_step(
 
         nD = cfg.first_k_dense
         if nD:
-            h, (c0, r0) = _scan(cfg)(
-                body, h, (params["dense_layers"], cache["c"][:nD], cache["kr"][:nD])
+            h, (c0, r0) = _over_layers(
+                cfg, body, h, params["dense_layers"], cache["c"][:nD], cache["kr"][:nD]
             )
-            h, (c1, r1) = _scan(cfg)(
-                body, h, (params["moe_layers"], cache["c"][nD:], cache["kr"][nD:])
+            h, (c1, r1) = _over_layers(
+                cfg, body, h, params["moe_layers"], cache["c"][nD:], cache["kr"][nD:]
             )
             cache = {"c": jnp.concatenate([c0, c1]), "kr": jnp.concatenate([r0, r1])}
         else:
-            h, (cc, ckr) = _scan(cfg)(body, h, (params["layers"], cache["c"], cache["kr"]))
+            h, (cc, ckr) = _over_layers(cfg, body, h, params["layers"], cache["c"], cache["kr"])
             cache = {"c": cc, "kr": ckr}
     elif at == "rwkv":
         def body(hh, inp):
@@ -782,7 +865,7 @@ def decode_step(
     else:
         raise ValueError(at)
 
-    logits = _logits(params, cfg, h)[:, 0, :]
+    logits = _logits(params, cfg, h, f32=True)[:, 0, :]
     return logits, cache
 
 
@@ -822,15 +905,18 @@ def _fill_slots(arr: jnp.ndarray, C: int) -> jnp.ndarray:
 def prefill(
     params: PyTree, cfg: LMConfig, batch: Dict, capacity: Optional[int] = None
 ) -> Tuple[jnp.ndarray, PyTree]:
-    """Parallel prefill: full forward for logits, collecting the decode
-    cache (rotated K/V, SSM/conv states, or MLA latents) in the same pass.
+    """Parallel prefill: full forward, collecting the decode cache
+    (rotated K/V, SSM/conv states, or MLA latents) in the same pass.
     Returns (last-token logits (B, V), cache ready for ``decode_step`` at
-    position S)."""
+    position S).  With ``batch["lengths"]`` (MLA stacks) the logits are
+    each row's at its last real token, and row b decodes next at position
+    ``lengths[b]``; only those rows' logits go through the head."""
     h = _embed(params, cfg, batch)
     B, S, _ = h.shape
     C = capacity or S
     positions = _positions(batch, B, S)
     positions_3d = batch.get("positions_3d")
+    valid = _valid(cfg, batch, S)
     at = cfg.arch_type
 
     if at in ("dense", "vlm", "moe") and not cfg.use_mla:
@@ -872,16 +958,16 @@ def prefill(
             if "mlp" in lp:
                 hh = hh + swiglu(lp["mlp"], rmsnorm(lp["norm2"], hh))
             else:
-                out, _ = moe_apply(lp["moe"], cfg.moe(), rmsnorm(lp["norm2"], hh))
+                out, _ = moe_apply(lp["moe"], cfg.moe(), rmsnorm(lp["norm2"], hh), valid)
                 hh = hh + out
             return hh, (_fill_slots(ckv, C), _fill_slots(kr, C))
 
         if cfg.first_k_dense:
-            h, (c0, r0) = _scan(cfg)(body, h, params["dense_layers"])
-            h, (c1, r1) = _scan(cfg)(body, h, params["moe_layers"])
+            h, (c0, r0) = _over_layers(cfg, body, h, params["dense_layers"])
+            h, (c1, r1) = _over_layers(cfg, body, h, params["moe_layers"])
             cache = {"c": jnp.concatenate([c0, c1]), "kr": jnp.concatenate([r0, r1])}
         else:
-            h, (cc, rr) = _scan(cfg)(body, h, params["layers"])
+            h, (cc, rr) = _over_layers(cfg, body, h, params["layers"])
             cache = {"c": cc, "kr": rr}
     elif at == "rwkv":
         def body(hh, lp):
@@ -935,8 +1021,12 @@ def prefill(
     else:
         raise ValueError(at)
 
-    logits = _logits(params, cfg, h)
-    return logits[:, -1, :], cache
+    if valid is None:
+        last = h[:, -1:, :]
+    else:
+        at_last = jnp.maximum(jnp.asarray(batch["lengths"]) - 1, 0)
+        last = jnp.take_along_axis(h, at_last[:, None, None], axis=1)
+    return _logits(params, cfg, last, f32=True)[:, 0, :], cache
 
 
 __all__ = [
@@ -945,6 +1035,10 @@ __all__ = [
     "init_params",
     "abstract_params",
     "forward",
+    "forward_hidden",
+    "lm_head",
+    "takes_ragged_rows",
+    "unstack_layers",
     "loss_fn",
     "init_cache",
     "decode_step",

@@ -47,21 +47,23 @@ __all__ = [
 
 
 def truncate_params(params: PyTree, cfg: LMConfig, exit_layer: int) -> PyTree:
-    """Early-exit params: first ``exit_layer`` layers + shared head."""
+    """Early-exit params: first ``exit_layer`` layers + shared head.  Layer
+    lists (``unstack_layers``) are cut without copying a weight."""
+    def head(stack, n):
+        if isinstance(stack, (list, tuple)):
+            return list(stack[:n])
+        return jax.tree.map(lambda a: a[:n], stack)
+
     p = {k: v for k, v in params.items() if k not in ("layers", "dense_layers", "moe_layers")}
     if "layers" in params:
-        p["layers"] = jax.tree.map(lambda a: a[:exit_layer], params["layers"])
+        p["layers"] = head(params["layers"], exit_layer)
     else:  # moe two-stack
         nD = cfg.first_k_dense
         take_dense = min(exit_layer, nD)
         take_moe = max(exit_layer - nD, 0)
         if take_dense:
-            p["dense_layers"] = jax.tree.map(
-                lambda a: a[:take_dense], params["dense_layers"]
-            )
-        p["moe_layers"] = jax.tree.map(lambda a: a[:take_moe], params["moe_layers"])
-        if not take_dense:
-            p.pop("dense_layers", None)
+            p["dense_layers"] = head(params["dense_layers"], take_dense)
+        p["moe_layers"] = head(params["moe_layers"], take_moe)
     return p
 
 
