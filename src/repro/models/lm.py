@@ -15,6 +15,7 @@ API (all functional):
   loss_fn(params, cfg, batch) scalar CE (+ MoE aux)
   init_cache(cfg, B, capacity[, abstract]) decode cache pytree
   prefill(params, cfg, batch, capacity) -> (last_logits, cache)
+  latent_prefill(params, cfg, batch, capacity[, start]) -> (hidden, cache, loads)  (MLA)
   decode_step(params, cfg, cache, tokens, pos) -> (logits, cache)
 
 Ragged rows (MLA stacks): ``batch["lengths"]`` (B,) marks each row's
@@ -450,12 +451,9 @@ def lm_head(params: PyTree, cfg: LMConfig, h: jnp.ndarray) -> jnp.ndarray:
 
 
 def _dense_block(lp, cfg: LMConfig, h, positions, positions_3d):
-    if cfg.use_mla:
-        a = mla_apply(lp["attn"], cfg.mla(), rmsnorm(lp["norm1"], h), positions)
-    else:
-        a = attention_apply(
-            lp["attn"], cfg.attn(), rmsnorm(lp["norm1"], h), positions, positions_3d
-        )
+    a = attention_apply(
+        lp["attn"], cfg.attn(), rmsnorm(lp["norm1"], h), positions, positions_3d
+    )
     h = h + constrain(a, "batch", None, None)
     h = h + swiglu(lp["mlp"], rmsnorm(lp["norm2"], h))
     return h
@@ -463,14 +461,64 @@ def _dense_block(lp, cfg: LMConfig, h, positions, positions_3d):
 
 def _moe_block(lp, cfg: LMConfig, h, positions, aux, valid=None):
     """One MoE layer: (h, aux + its aux loss, its (E,) expert load)."""
-    if cfg.use_mla:
-        a = mla_apply(lp["attn"], cfg.mla(), rmsnorm(lp["norm1"], h), positions)
-    else:
-        a = attention_apply(lp["attn"], cfg.attn(), rmsnorm(lp["norm1"], h), positions)
+    a = attention_apply(lp["attn"], cfg.attn(), rmsnorm(lp["norm1"], h), positions)
     h = h + constrain(a, "batch", None, None)
     hn = rmsnorm(lp["norm2"], h)
     out, aux_l = moe_apply(lp["moe"], cfg.moe(), hn, valid)
     return h + out, aux + aux_l, moe_load(lp["moe"], cfg.moe(), hn, valid)
+
+
+def _mla_layer(lp, cfg: LMConfig, h, positions, valid, capacity: Optional[int]):
+    """One layer of an MLA stack: latent attention, then the dense SwiGLU
+    MLP or the MoE layer (whose dropless experts route the ``valid``
+    tokens).  Returns (h, its aux loss, its latent cache (ckv, kr) filled
+    into ``capacity`` slots or None without one, its (E,) expert load or
+    None on a dense layer)."""
+    a, kv = mla_apply(lp["attn"], cfg.mla(), rmsnorm(lp["norm1"], h), positions,
+                      return_kv=True)
+    h = h + constrain(a, "batch", None, None)
+    hn = rmsnorm(lp["norm2"], h)
+    kv = None if capacity is None else tuple(_fill_slots(x, capacity) for x in kv)
+    if "mlp" in lp:
+        return h + swiglu(lp["mlp"], hn), jnp.zeros((), jnp.float32), kv, None
+    out, aux = moe_apply(lp["moe"], cfg.moe(), hn, valid)
+    return h + out, aux, kv, moe_load(lp["moe"], cfg.moe(), hn, valid)
+
+
+def _mla_stack(params, cfg: LMConfig, h, positions, valid, *, start: int = 0,
+               capacity: Optional[int] = None):
+    """Layers ``start`` … L−1 of an MLA stack (``dense_layers`` then
+    ``moe_layers``, stacked or per-layer lists) over hidden states ``h``,
+    the one layer body of ``forward_hidden`` and ``prefill``.  Returns (h,
+    aux loss, the latent cache {"c", "kr"} of those layers at ``capacity``
+    slots or None without one, their (MoE layers, E) expert loads or
+    None)."""
+    def body(carry, lp):
+        hh, ax = carry
+        hh, a, kv, load = _mla_layer(lp, cfg, hh, positions, valid, capacity)
+        return (hh, ax + a), (kv, load)
+
+    body = _maybe_remat(body, cfg)
+    carry = (h, jnp.zeros((), jnp.float32))
+    kvs, loads, first = [], [], 0
+    for group in ("dense_layers", "moe_layers"):
+        layers = params.get(group)
+        n = 0 if layers is None else (
+            len(layers) if isinstance(layers, (list, tuple))
+            else jax.tree.leaves(layers)[0].shape[0])
+        skip, first = min(max(start - first, 0), n), first + n
+        if skip == n:
+            continue
+        if skip:
+            layers = (layers[skip:] if isinstance(layers, (list, tuple))
+                      else jax.tree.map(lambda a: a[skip:], layers))
+        carry, (kv, load) = _over_layers(cfg, body, carry, layers)
+        kvs.append(kv)
+        if load is not None:
+            loads.append(load)
+    cache = None if capacity is None else {
+        "c": jnp.concatenate([c for c, _ in kvs]), "kr": jnp.concatenate([r for _, r in kvs])}
+    return carry + (cache, jnp.concatenate(loads) if loads else None)
 
 
 def _rwkv_block(lp, cfg: LMConfig, h, state, x_tm, x_cm):
@@ -523,6 +571,8 @@ def forward_hidden(params: PyTree, cfg: LMConfig, batch: Dict):
             cfg,
         )
         h, _ = _scan(cfg)(body, h, params["layers"])
+    elif at == "moe" and cfg.use_mla:
+        h, aux, _, loads = _mla_stack(params, cfg, h, positions, valid)
     elif at == "moe":
         if cfg.first_k_dense:
             body_d = _maybe_remat(
@@ -951,24 +1001,7 @@ def prefill(
             h, ys = _scan(cfg)(body, h, params["layers"])
             cache = dict(zip(fields, ys))
     elif at == "moe" and cfg.use_mla:
-        def body(hh, lp):
-            hn = rmsnorm(lp["norm1"], hh)
-            a, (ckv, kr) = mla_apply(lp["attn"], cfg.mla(), hn, positions, return_kv=True)
-            hh = hh + constrain(a, "batch", None, None)
-            if "mlp" in lp:
-                hh = hh + swiglu(lp["mlp"], rmsnorm(lp["norm2"], hh))
-            else:
-                out, _ = moe_apply(lp["moe"], cfg.moe(), rmsnorm(lp["norm2"], hh), valid)
-                hh = hh + out
-            return hh, (_fill_slots(ckv, C), _fill_slots(kr, C))
-
-        if cfg.first_k_dense:
-            h, (c0, r0) = _over_layers(cfg, body, h, params["dense_layers"])
-            h, (c1, r1) = _over_layers(cfg, body, h, params["moe_layers"])
-            cache = {"c": jnp.concatenate([c0, c1]), "kr": jnp.concatenate([r0, r1])}
-        else:
-            h, (cc, rr) = _over_layers(cfg, body, h, params["layers"])
-            cache = {"c": cc, "kr": rr}
+        h, _, cache, _ = _mla_stack(params, cfg, h, positions, valid, capacity=C)
     elif at == "rwkv":
         def body(hh, lp):
             hh, st, xt, xc = _rwkv_block(lp, cfg, hh, None, None, None)
@@ -1021,12 +1054,36 @@ def prefill(
     else:
         raise ValueError(at)
 
-    if valid is None:
+    return last_logits(params, cfg, h, None if valid is None else batch["lengths"]), cache
+
+
+def latent_prefill(params: PyTree, cfg: LMConfig, batch: Dict, capacity: int, *,
+                   start: int = 0):
+    """Prefill of an MLA stack that keeps what ``prefill`` drops: layers
+    ``start`` … L−1 over ``batch``, from its token ids (``start`` 0) or from
+    ``batch["hidden"]`` (B, S, M), the hidden states after layer ``start``
+    of an earlier pass over the same prompts.  Returns (the hidden states
+    (B, S, M) before the final norm, the latent cache {"c", "kr"} of those
+    layers at ``capacity`` slots, their (MoE layers, E) expert loads over
+    the real tokens)."""
+    h = batch["hidden"] if start else _embed(params, cfg, batch)
+    B, S, _ = h.shape
+    h, _, cache, loads = _mla_stack(params, cfg, h, _positions(batch, B, S),
+                                    _valid(cfg, batch, S), start=start, capacity=capacity)
+    return h, cache, loads
+
+
+def last_logits(params: PyTree, cfg: LMConfig, h: jnp.ndarray,
+                lengths: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """float32 logits (B, V) of (B, S, M) hidden states at each row's last
+    real token (``lengths``), or at the last position; only those rows'
+    positions go through the head."""
+    if lengths is None:
         last = h[:, -1:, :]
     else:
-        at_last = jnp.maximum(jnp.asarray(batch["lengths"]) - 1, 0)
+        at_last = jnp.maximum(jnp.asarray(lengths) - 1, 0)
         last = jnp.take_along_axis(h, at_last[:, None, None], axis=1)
-    return _logits(params, cfg, last, f32=True)[:, 0, :], cache
+    return _logits(params, cfg, last, f32=True)[:, 0, :]
 
 
 __all__ = [
@@ -1039,6 +1096,8 @@ __all__ = [
     "lm_head",
     "takes_ragged_rows",
     "unstack_layers",
+    "latent_prefill",
+    "last_logits",
     "loss_fn",
     "init_cache",
     "decode_step",

@@ -10,6 +10,7 @@ expanded form, so logits agree to float32 rounding of O(1) values
 through three layers: 2e-5 absolute, about 100x the observed gap."""
 import importlib.util
 import os
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,8 @@ from repro.models.layers import (
     yarn_inv_freq,
 )
 from repro.models.lm import init_params
-from repro.obs import jit_stats
+from repro.obs import Obs, jit_stats
+from repro.serving.cascade_serving import truncate_params, truncated_config
 from repro.serving.decode_loop import CascadePrograms, cascade_generate, generate
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -50,11 +52,16 @@ LENGTHS = np.array([5, 9, 12])
 
 
 @pytest.fixture(scope="module")
-def model():
+def obs():
+    return Obs(metrics=True, tracing=False, profiling=False)
+
+
+@pytest.fixture(scope="module")
+def model(obs):
     cfg = from_hf(TINY, dtype="float32")
     params = init_params(cfg, jax.random.PRNGKey(0))
     progs = CascadePrograms(params, cfg, EXIT, steps=STEPS, row_buckets=[1, 2, 4],
-                            length_buckets=[16], max_tokens=64)
+                            length_buckets=[16], max_tokens=64, obs=obs)
     tokens = np.random.default_rng(1).integers(0, TINY["vocab_size"], (3, 12)).astype(np.int32)
     for i, n in enumerate(LENGTHS):
         tokens[i, n:] = 0
@@ -123,6 +130,78 @@ def test_weak_pass_features_match_reference(model):
                                    atol=1e-7)
     # 3 of 8 experts per token: the heaviest takes between 1/8 and 1/3
     assert 1 / 8 <= share <= 1 / 3
+
+
+class _GivenDecisions:
+    """A session whose decisions are given: row i offloads where
+    ``offload[i]``."""
+
+    def __init__(self, offload):
+        self.offload = offload
+        self.telemetry = SimpleNamespace(as_dict=dict)
+
+    def submit_batch(self, features):
+        assert len(features) == len(self.offload)
+        return [SimpleNamespace(offload=bool(o), estimate=float(o)) for o in self.offload]
+
+
+@pytest.mark.parametrize("offload", [[False, False, False], [True, True, True],
+                                     [True, False, True]], ids=["weak", "strong", "mixed"])
+def test_one_weak_prefill_answers_as_two_prefills(model, offload):
+    """The weak pass's kept cache and logits answer the weak rows, and the
+    strong rows start at the exit layer from its hidden states: the same
+    tokens as ``generate`` on each stack alone, and the same answer logits
+    as each stack's own prefill of its rows."""
+    cfg, params, progs, tokens = model
+    out = cascade_generate(params, cfg, {"tokens": tokens, "lengths": LENGTHS}, STEPS,
+                           session=_GivenDecisions(offload), exit_layer=EXIT, programs=progs)
+    stacks = {"weak": (truncate_params(params, cfg, EXIT), truncated_config(cfg, EXIT)),
+              "strong": (params, cfg)}
+    assert sorted(out["answer_logits"]) == sorted({"strong" if o else "weak" for o in offload})
+    for stack, (idx, logits) in out["answer_logits"].items():
+        np.testing.assert_array_equal(idx, np.flatnonzero(np.array(offload) == (stack == "strong")))
+        s = int(LENGTHS[idx].max())
+        rows = {"tokens": tokens[idx, :s], "lengths": LENGTHS[idx]}
+        alone = np.asarray(generate(*stacks[stack], rows, STEPS))
+        np.testing.assert_array_equal(out["tokens"][idx], alone)
+        answers, two_pass = progs.generate(stack, rows["tokens"], rows["lengths"])
+        np.testing.assert_array_equal(answers, alone)
+        # a mixed block's rows went through the first layers in a batch of
+        # another row count: only summation order differs, which near-zero
+        # logits carry as float32 rounding of O(1) sums
+        np.testing.assert_allclose(np.asarray(logits[: idx.size]),
+                                   np.asarray(two_pass[: idx.size]), rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("arch", ["deepseek_v2", "yi_6b"])
+def test_reused_tokens_count_what_the_weak_pass_prefilled(model, obs, arch):
+    """``repro_cascade_reused_tokens_total`` counts every real prompt token
+    of an MLA block, under the stack that answers it, and none on a stack
+    that prefills each stack's rows again."""
+    if arch == "yi_6b":
+        from repro.configs import get_config
+        from repro.models.lm import reduced
+
+        cfg = reduced(get_config("yi_6b"), num_layers=2)
+        params = init_params(cfg, jax.random.PRNGKey(0))
+        obs = Obs(metrics=True, tracing=False, profiling=False)
+        progs = CascadePrograms(params, cfg, 1, steps=2, obs=obs)
+        tokens, lengths = np.ones((3, 8), np.int32), np.full(3, 8)
+    else:
+        cfg, params, progs, tokens = model
+        lengths = LENGTHS
+    before = obs.metrics.snapshot()
+    offload = [True, False, True]
+    cascade_generate(params, cfg, {"tokens": tokens, "lengths": lengths}, progs.steps,
+                     session=_GivenDecisions(offload), exit_layer=progs.exit_layer,
+                     programs=progs)
+    got = obs.metrics.delta(before, obs.metrics.snapshot())
+    real = {"strong": int(lengths[[0, 2]].sum()), "weak": int(lengths[1])}
+    for stack in ("weak", "strong"):
+        prefilled = got[f'repro_cascade_tokens_total{{phase="prefill",stack="{stack}"}}']
+        reused = got[f'repro_cascade_reused_tokens_total{{stack="{stack}"}}']
+        assert prefilled == real[stack]
+        assert reused == (real[stack] if arch == "deepseek_v2" else 0)
 
 
 def _moe_case(norm_topk: bool, forced: bool):
