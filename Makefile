@@ -2,33 +2,13 @@
 #
 #   make install      editable install of src/repro (replaces the PYTHONPATH=src hack)
 #   make test         tier-1 test suite
-#   make bench        benchmark harness (writes artifacts/bench_results.csv)
-#   make bench-smoke  artifact-free benches only, incl. the video pipeline
-#                     (CI; writes bench_results_smoke.csv); fails first if a
-#                     bench_* function is missing from the selection registry
-#   make bench-check  just the registry completeness guard
-#   make bench-compare  perf gate: diff the two most recent
-#                     artifacts/BENCH_<rev>.json, fail on >15% median
-#                     regressions (exit 0 when fewer than two artifacts)
 
 PY ?= python
 
-.PHONY: install test bench bench-smoke bench-check bench-compare
+.PHONY: install test
 
 install:
 	$(PY) -m pip install -e .
 
 test:
 	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) -m pytest -x -q
-
-bench:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) benchmarks/run.py
-
-bench-check:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) benchmarks/run.py --check
-
-bench-smoke: bench-check
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) benchmarks/run.py --smoke
-
-bench-compare:
-	PYTHONPATH=src$${PYTHONPATH:+:$$PYTHONPATH} $(PY) benchmarks/compare.py

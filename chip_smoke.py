@@ -10,8 +10,8 @@ One chip:
             top-25) into a one-hidden-layer (128) MLP, fitted on seeded
             synthetic detections; held-out estimates must track the reward
   decide    ``engine.decide`` on a 1024-image block through the compiled
-            fused score kernel, against the ``lax`` path and a float64 host
-            forward pass
+            fused score kernel, against the ``reference`` path and a float64
+            host forward pass
   simulate  ``simulate()`` over ``default_congested_fleet`` on a 2048-frame
             stream
   session   ``OffloadSession.submit_batch``: the fused fast route against
@@ -61,7 +61,7 @@ from repro.core.features import extract_features_batch, feature_dim  # noqa: E40
 from repro.detection.batch import DetectionsBatch, GroundTruthBatch, match_batch  # noqa: E402
 from repro.fleet import FleetPlane, default_city_scenario, run_city_scenario  # noqa: E402
 from repro.fleet.runtime import simulate_fleet  # noqa: E402
-from repro.kernels.dispatch import resolve_interpret, resolve_path  # noqa: E402
+from repro.kernels.dispatch import resolve_path  # noqa: E402
 from repro.kernels.score_pipeline import ops as score_ops  # noqa: E402
 from repro.launch.mesh import make_fleet_mesh  # noqa: E402
 from repro.obs import jit_stats  # noqa: E402
@@ -101,18 +101,15 @@ PACKED_ROWS = (1, 2, 7, 13, 31, 38, 63, 64)
 
 @dataclass(frozen=True)
 class Paths:
-    """The kernel paths the phases must find resolved, and whether the
+    """The kernel path the phases must find resolved, and whether the
     lowered programs must hold a compiled Mosaic kernel."""
 
-    pipeline: str  # resolve_pipeline_path(None)
     kernel: str  # repro.kernels.dispatch.resolve_path(None)
-    tracker_interpret: bool  # resolve_interpret(None)
     mosaic: bool  # lowered programs contain tpu_custom_call
 
     @classmethod
     def tpu(cls) -> "Paths":
-        return cls(pipeline="pallas", kernel="compiled",
-                   tracker_interpret=False, mosaic=True)
+        return cls(kernel="compiled", mosaic=True)
 
 
 class CheckFailed(RuntimeError):
@@ -129,13 +126,8 @@ def _has_mosaic(jitted, *args, **kwargs) -> bool:
 
 
 def _check_paths(paths: Paths) -> None:
-    got = (
-        score_ops.resolve_pipeline_path(None),
-        resolve_path(None),
-        resolve_interpret(None),
-    )
-    want = (paths.pipeline, paths.kernel, paths.tracker_interpret)
-    check(got == want, f"kernel paths resolved to {got}, expected {want}")
+    got = resolve_path(None)
+    check(got == paths.kernel, f"kernel paths resolved to {got!r}, expected {paths.kernel!r}")
 
 
 # ------------------------------------------------------------------ data
@@ -238,11 +230,11 @@ def phase_decide(engine: OffloadEngine, seed: int, paths: Paths,
                 db.boxes, db.scores, db.classes, db.mask,
                 params["w1"], params["b1"], params["w2"], params["b2"],
                 params["mu"], params["sigma"], np.float32(fx.image_size),
-                fx.num_classes, fx.top_k, 128, False,
+                fx.num_classes, fx.top_k, 128, paths.kernel,
             ),
             "the fused score program holds no compiled Mosaic kernel",
         )
-    lax = np.asarray(score_ops.score_pipeline(db, params, path="lax", **kw))
+    lax = np.asarray(score_ops.score_pipeline(db, params, path="reference", **kw))
     ref = _f64_scores(engine, extract_features_batch(db, **kw))
     d_lax = float(np.max(np.abs(dec.estimates - lax)))
     d_ref = float(np.max(np.abs(dec.estimates - ref)))
@@ -250,15 +242,15 @@ def phase_decide(engine: OffloadEngine, seed: int, paths: Paths,
     flips = int(np.sum(dec.offload != engine.policy.decide_batch(lax)))
     check(dec.estimates.shape == (n_images,), f"estimates shape {dec.estimates.shape}")
     check(bool(np.isfinite(dec.estimates).all()), "non-finite estimates")
-    check(d_lax <= EST_TOL, f"max|{paths.pipeline}-lax|={d_lax:.3g} > {EST_TOL}")
-    check(d_ref <= EST_TOL, f"max|{paths.pipeline}-f64|={d_ref:.3g} > {EST_TOL}")
+    check(d_lax <= EST_TOL, f"max|{paths.kernel}-reference|={d_lax:.3g} > {EST_TOL}")
+    check(d_ref <= EST_TOL, f"max|{paths.kernel}-f64|={d_ref:.3g} > {EST_TOL}")
     return {
         "shapes": f"images={n_images},boxes={MAX_BOXES},top_k={TOP_K},classes={NUM_CLASSES}",
         "check": (
-            f"path={paths.pipeline} max|{paths.pipeline}-lax|={d_lax:.3e}"
-            f" max|{paths.pipeline}-f64|={d_ref:.3e} max|lax-f64|={d_lax_ref:.3e}"
+            f"path={paths.kernel} max|{paths.kernel}-reference|={d_lax:.3e}"
+            f" max|{paths.kernel}-f64|={d_ref:.3e} max|reference-f64|={d_lax_ref:.3e}"
             f" tol={EST_TOL:g}"
-            f" decisions_differing_from_lax={flips} ratio={dec.ratio:.4f}"
+            f" decisions_differing_from_reference={flips} ratio={dec.ratio:.4f}"
         ),
     }
 
@@ -315,7 +307,7 @@ def phase_packed(seed: int, paths: Paths, rows: Tuple[int, ...] = PACKED_ROWS,
                  per_size: int = 8) -> Dict:
     """Host blocks cross as one packed buffer per scoring call; their
     estimates must equal those of the same rows passed as device arrays
-    (the four-array route) bit for bit, on the resolved path and ``lax``."""
+    (the four-array route) bit for bit, on the resolved path and reference."""
     _check_paths(paths)
     rng = np.random.default_rng(seed + 6)
     F = feature_dim(COCO_CLASSES, COCO_SLOTS)
@@ -328,7 +320,7 @@ def phase_packed(seed: int, paths: Paths, rows: Tuple[int, ...] = PACKED_ROWS,
         "sigma": rng.uniform(0.5, 2.0, F),
     }.items()}
     kw = dict(num_classes=COCO_CLASSES, top_k=COCO_SLOTS, image_size=COCO_IMAGE)
-    pipeline_paths = tuple(dict.fromkeys((paths.pipeline, "lax")))
+    pipeline_paths = tuple(dict.fromkeys((paths.kernel, "reference")))
     worst, unequal, blocks = 0.0, 0, 0
     calls0 = jit_stats.snapshot()["score_pipeline.packed"][1]
     for path in pipeline_paths:
@@ -365,7 +357,7 @@ def phase_tracker(seed: int, paths: Paths, n_streams: int = 8,
         check(
             _has_mosaic(
                 track_mod._scan_jit, track_mod._init_state(n_streams, cfg),
-                frames, cfg, False,
+                frames, cfg, paths.kernel,
             ),
             "the tracker scan holds no compiled Mosaic IoU kernel",
         )

@@ -1,4 +1,4 @@
-"""The paper's full evaluation (Figs. 5/9/10, Tables II) from the cached
+"""The paper's full evaluation (Figs. 5/6/9/10, Table II) from the cached
 pipeline — runs the complete experiment suite, prints a summary, then fits
 the deployable ``OffloadEngine`` artifact and round-trips it through
 save/load.
@@ -28,6 +28,13 @@ def main() -> None:
     f5 = results["figure5"]
     for e, m in zip(f5["context_sizes"], f5["curves"]["r=0.2"]["mean"]):
         print(f"  |E|={e:4d}: {m:.4f}")
+    print("\nTable II (share of images, mAP weak / strong, by reward sign):")
+    for k, v in results["table2"].items():
+        print(f"  {k:12s} {v['pct']:5.1f}%  {v['weak_map']:.4f} / {v['strong_map']:.4f}")
+    print("\nFig. 6 (TIDE delta-mAP per error type):")
+    for policy, e in results["figure6"].items():
+        cats = ("cls", "loc", "cls_loc", "dupe", "bkg", "miss")
+        print(f"  {policy:6s} " + " ".join(f"{c}={e[c]:.4f}" for c in cats))
     print("\nFig. 10 (normalized mAP, % of weak->strong gap closed):")
     for name, cur in results["figure9_10"]["curves"].items():
         pts = ", ".join(f"{v:.0f}" for v in cur["norm"][:6])

@@ -1,12 +1,10 @@
 """End-to-end driver: train a ~100M-parameter dense LM for a few hundred
-steps on synthetic token streams using the framework's train_step (the same
-code path the dry-run lowers for the production mesh).
+steps on synthetic token streams using the framework's train_step.
 
 Run:  PYTHONPATH=src python examples/train_lm.py [--steps 150] [--arch yi_6b]
 
 The model is the assigned architecture's family scaled to ~100M params so
-the driver completes on CPU; on a real mesh the full config lowers
-identically (see launch/dryrun.py).
+the driver completes on CPU.
 """
 import argparse
 import dataclasses

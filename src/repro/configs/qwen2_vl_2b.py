@@ -2,7 +2,7 @@
 
 28L d_model=1536 12H (GQA kv=2) d_ff=8960 vocab=151936.  M-RoPE with
 (temporal, height, width) frequency sections (16, 24, 24) over head_dim/2.
-The ViT encoder + projector is STUBBED: ``input_specs`` supplies projected
+The ViT encoder + projector is STUBBED: the caller supplies projected
 patch embeddings as a vision prefix (dynamic-resolution handled upstream).
 """
 from repro.models.lm import LMConfig

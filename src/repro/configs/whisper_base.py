@@ -1,7 +1,7 @@
 """Whisper-base [arXiv:2212.04356] — encoder-decoder, audio.
 
 6L (enc) + 6L (dec), d_model=512, 8H (full MHA), d_ff=2048, vocab=51865.
-The mel-spectrogram + conv1/conv2 frontend is STUBBED: ``input_specs``
+The mel-spectrogram + conv1/conv2 frontend is STUBBED: the caller
 provides (B, 1500, 512) frame embeddings.  Decoder positions are
 sinusoidal here (the release uses a learned 448-slot table; our assigned
 decode shapes exceed it — deviation recorded in DESIGN.md).

@@ -8,7 +8,7 @@ Stages (all cached under ``artifacts/``):
   5. evaluate every policy (oracle + estimated + baselines) across ratios.
 
 Each paper figure/table has a ``figure_*``/``table_*`` function reading from
-the cached pipeline state; ``benchmarks/`` and ``examples/`` call into these.
+the cached pipeline state; ``examples/offload_detection.py`` runs them all.
 """
 from __future__ import annotations
 

@@ -1,11 +1,5 @@
-"""Backend-aware kernel path resolution, shared by the serve-time kernels.
-
-Every Pallas wrapper in this package used to expose ``interpret: bool`` —
-compiled lowering vs the Pallas interpreter.  That binary missed the case
-CI actually runs: on CPU the interpreter is frequently *slower* than the
-plain-jnp oracle (``artifacts/BENCH_fc34508.json`` recorded the interpreted
-IoU kernel at 1.75x the jnp reference), so "auto" must be a three-way
-choice:
+"""The one kernel-path rule: every kernel in this package, and the video
+tracker, resolves its ``interpret=`` / ``path=`` argument to one of:
 
 ``"compiled"``
     The real Pallas lowering — TPU (and GPU triton).
@@ -17,16 +11,15 @@ choice:
     CPU, and trivially shard-safe (elementwise/per-row math, no grid-shape
     compilation regimes).
 
-``resolve_path(None)`` picks ``"reference"`` on CPU and ``"compiled"``
-everywhere else; booleans keep their historical meaning (``True`` →
-interpreter, ``False`` → compiled) so existing callers that thread
-``interpret=`` flags through are unchanged.  The resolution is
+``resolve_path(None)`` picks ``"reference"`` on CPU (where the interpreter
+is slower than the jnp oracle) and ``"compiled"`` everywhere else;
+``True`` → interpreter, ``False`` → compiled.  The resolution is
 deterministic per process — no runtime autotuning — because the fleet
 plane's bit-identity contract compares results across processes.
 """
 from __future__ import annotations
 
-from typing import Optional, Union
+from typing import Union
 
 import jax
 
@@ -49,13 +42,3 @@ def resolve_path(interpret: Union[None, bool, str] = None) -> str:
             )
         return interpret
     return "interpret" if interpret else "compiled"
-
-
-def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """The legacy two-way resolution for callers that only choose between
-    the Pallas lowerings: ``None`` → interpreter exactly where no compiled
-    lowering exists (CPU).  Kept for the video tracker and forced-mode
-    benchmarks; new code should prefer :func:`resolve_path`."""
-    if interpret is None:
-        return jax.default_backend() == "cpu"
-    return bool(interpret)

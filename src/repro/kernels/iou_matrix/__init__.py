@@ -1,7 +1,6 @@
 from repro.kernels.iou_matrix.ops import (
     iou_matrix,
     iou_matrix_batch,
-    resolve_interpret,
     resolve_path,
 )
 from repro.kernels.iou_matrix.ref import iou_matrix_batch_ref, iou_matrix_ref
@@ -11,6 +10,5 @@ __all__ = [
     "iou_matrix_batch",
     "iou_matrix_batch_ref",
     "iou_matrix_ref",
-    "resolve_interpret",
     "resolve_path",
 ]

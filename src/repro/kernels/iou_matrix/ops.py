@@ -1,17 +1,12 @@
 """jit'd wrappers: pad to tile multiples, transpose to the lane-aligned
 ``(..., 4, N)`` layout, call the Pallas kernel, crop.
 
-``interpret=None`` (the default) resolves through
-:func:`repro.kernels.dispatch.resolve_path`: the jnp reference on CPU
-(where the Pallas interpreter measured *slower* than the oracle — the
-BENCH_fc34508 regression), compiled Pallas on TPU/GPU.  Booleans force the
-interpreter/compiled lowerings as before; the string ``"reference"``
-forces the oracle.
+``interpret=`` takes a kernel path as
+:func:`repro.kernels.dispatch.resolve_path` defines it.
 
 Tile sizes default per path (``None``): the compiled lowering keeps the
 MXU-friendly 256-lane tiles; interpreter mode shrinks tiles to the padded
-problem so small inputs stop paying for 256x256 zero-padding (the
-tile-size sweep behind the defaults lives in ``bench_iou``).
+problem so small inputs stop paying for 256x256 zero-padding.
 """
 from __future__ import annotations
 
@@ -21,7 +16,7 @@ from typing import Optional, Union
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.dispatch import resolve_interpret, resolve_path
+from repro.kernels.dispatch import resolve_path
 from repro.kernels.iou_matrix.kernel import iou_matrix_batch_pallas, iou_matrix_pallas
 from repro.kernels.iou_matrix.ref import iou_matrix_batch_ref, iou_matrix_ref
 from repro.obs.jit_stats import register_jit
@@ -29,7 +24,6 @@ from repro.obs.jit_stats import register_jit
 __all__ = [
     "iou_matrix",
     "iou_matrix_batch",
-    "resolve_interpret",
     "resolve_path",
 ]
 

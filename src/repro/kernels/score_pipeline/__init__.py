@@ -1,15 +1,8 @@
-from repro.kernels.score_pipeline.ops import (
-    PIPELINE_PATHS,
-    pipeline_params,
-    resolve_pipeline_path,
-    score_pipeline,
-)
+from repro.kernels.score_pipeline.ops import pipeline_params, score_pipeline
 from repro.kernels.score_pipeline.ref import score_pipeline_ref
 
 __all__ = [
-    "PIPELINE_PATHS",
     "pipeline_params",
-    "resolve_pipeline_path",
     "score_pipeline",
     "score_pipeline_ref",
 ]

@@ -5,23 +5,20 @@ between stages.
 The composed path exits to numpy twice per block (features →
 ``np.asarray``, scores → ``np.asarray``) and re-enters jit three times.
 Here the whole pipeline — top-k feature extraction, standardize, estimator
-MLP — runs as ONE jitted dispatch:
+MLP — runs as ONE jitted dispatch, on a kernel path of
+:func:`repro.kernels.dispatch.resolve_path`:
 
-``"lax"``
+``"reference"``
     The portable composition: ``score_pipeline_ref`` under ``jax.jit``.
     Because ``estimator_mlp`` resolves to the same plain-jnp MLP math on
     CPU, this path is **bit-identical** to the composed
     ``extract_features_batch → MLPRewardModel.predict`` route (the
     property tests pin this down), while fusing away the host round-trips.
-``"pallas"`` / ``"pallas_interpret"``
+``"compiled"`` / ``"interpret"``
     The fused Pallas kernel (``kernel.py``): confidence top-k gather stays
     outside (data-dependent ``argsort``), everything downstream — per-box
     features, global stats, standardize, both MLP layers — is one kernel
     with intermediates resident in VMEM.
-
-``path=None`` auto-resolves: ``"pallas"`` where a compiled lowering exists
-(TPU/GPU), ``"lax"`` on CPU (the interpreter would be slower than the jit
-— the same reasoning as ``repro.kernels.dispatch.resolve_path``).
 
 A block of host (numpy) arrays crosses to the device as ONE packed
 ``uint8`` buffer (:func:`pack_detections`), unpacked bit for bit inside the
@@ -36,7 +33,7 @@ to the fused path), and a donated block would be deleted by the first call.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Optional, Tuple, Union
+from typing import Dict, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +42,7 @@ from jax import lax
 
 from repro.core.features import feature_dim
 from repro.detection.batch import DetectionsBatch
+from repro.kernels.dispatch import resolve_path
 from repro.kernels.score_pipeline.kernel import (
     N_BOX_STATS,
     N_GLOBAL_STATS,
@@ -52,20 +50,6 @@ from repro.kernels.score_pipeline.kernel import (
 )
 from repro.kernels.score_pipeline.ref import score_pipeline_ref
 from repro.obs.jit_stats import count_call, register_jit
-
-PIPELINE_PATHS = ("lax", "pallas", "pallas_interpret")
-
-
-def resolve_pipeline_path(path: Optional[str] = None) -> str:
-    """``None`` → ``"pallas"`` on TPU/GPU, ``"lax"`` on CPU."""
-    if path is None:
-        return "lax" if jax.default_backend() == "cpu" else "pallas"
-    if path not in PIPELINE_PATHS:
-        raise ValueError(
-            f"unknown score-pipeline path {path!r}; use one of {PIPELINE_PATHS}"
-        )
-    return path
-
 
 def pipeline_params(model) -> Dict[str, jnp.ndarray]:
     """The device param bundle ``score_pipeline`` consumes, from a *fused*
@@ -110,11 +94,11 @@ def _ceil_to(n: int, multiple: int) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_classes", "top_k", "tile_b", "interpret")
+    jax.jit, static_argnames=("num_classes", "top_k", "tile_b", "path")
 )
 def _score_pipeline_pallas(
     boxes, scores, classes, mask, w1, b1, w2, b2, mu, sigma,
-    image_size, num_classes, top_k, tile_b, interpret,
+    image_size, num_classes, top_k, tile_b, path,
 ):
     K = scores.shape[1]
     if K < top_k:  # the kernel slices a fixed top_k window
@@ -165,7 +149,7 @@ def _score_pipeline_pallas(
         s_p, m_p, cls_p, bx_p, w1_box, mu_box, sig_box,
         w1_glob, mu_glob, sig_glob, b1_p, w2_p, b2_p,
         num_classes=num_classes, top_k=top_k, tile_b=tile_b,
-        interpret=interpret,
+        interpret=path == "interpret",
     )
     return out[:B, 0]
 
@@ -221,13 +205,13 @@ def _score_packed(
     """The scoring program of a packed host block: unpack, then exactly the
     body the four-array route runs on ``path``."""
     det = unpack_detections(packed)
-    if path == "lax":
+    if path == "reference":
         return score_pipeline_ref(
             *det, w1, b1, w2, b2, mu, sigma, image_size, num_classes, top_k
         )
     return _score_pipeline_pallas(
         *det, w1, b1, w2, b2, mu, sigma, image_size,
-        num_classes, top_k, tile_b, path == "pallas_interpret",
+        num_classes, top_k, tile_b, path,
     )
 
 
@@ -249,7 +233,7 @@ def score_pipeline(
     num_classes: int,
     top_k: int = 25,
     image_size: float = 1.0,
-    path: Optional[str] = None,
+    path: Union[None, bool, str] = None,
     tile_b: int = 128,
 ) -> jnp.ndarray:
     """(B,) device-resident reward estimates for a padded detection block.
@@ -276,7 +260,7 @@ def score_pipeline(
         )
     if scores.shape[0] == 0:
         return jnp.zeros((0,), jnp.float32)
-    resolved = resolve_pipeline_path(path)
+    resolved = resolve_path(path)
     weights = tuple(params[k] for k in ("w1", "b1", "w2", "b2", "mu", "sigma"))
     size = _device_scalar(float(image_size))
     if all(
@@ -289,11 +273,11 @@ def score_pipeline(
             int(top_k), int(tile_b), resolved,
         )
     # device-resident (or other-dtype) arrays go straight into the jit
-    if resolved == "lax":
+    if resolved == "reference":
         return _score_pipeline_lax(
             *arrays, *weights, size, int(num_classes), int(top_k),
         )
     return _score_pipeline_pallas(
         *arrays, *weights, size, int(num_classes), int(top_k),
-        int(tile_b), resolved == "pallas_interpret",
+        int(tile_b), resolved,
     )

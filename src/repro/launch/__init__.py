@@ -1,1 +1,1 @@
-"""Launch layer: mesh construction, sharding rules, dry-run, drivers."""
+"""Launch layer: mesh construction, sharding rules, drivers."""

@@ -1,6 +1,6 @@
 """Step builders: train_step / prefill_step / serve_step per architecture.
 
-These are the functions the dry-run lowers and the drivers jit.  All take
+These are the functions the drivers jit.  All take
 ``cfg`` statically (closures) so ``jax.jit`` sees pure array signatures.
 """
 from __future__ import annotations

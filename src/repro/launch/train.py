@@ -1,12 +1,9 @@
 """Training launcher.
 
-Local mode (default): trains a scaled-down variant of ``--arch`` on
-synthetic tokens with the SAME train_step the dry-run lowers for the
-production mesh.  ``--dryrun`` delegates to repro.launch.dryrun for the
-mesh lowering (512 host devices).
+Trains a scaled-down variant of ``--arch`` on synthetic tokens with the
+framework's train_step.
 
   PYTHONPATH=src python -m repro.launch.train --arch yi_6b --steps 30
-  PYTHONPATH=src python -m repro.launch.dryrun --arch yi_6b --shape train_4k
 """
 from __future__ import annotations
 

@@ -14,7 +14,7 @@ threaded as an optional ``obs=`` argument through ``OffloadSession``,
 
 ``obs=None`` (the default everywhere) is the noop: instrumented code
 guards every emission behind one ``is None`` check, so the disabled cost
-is below the noise floor (``bench_obs_overhead`` asserts <3%).
+is one branch per emission site.
 
 Three sub-planes, each independently disableable:
 
@@ -112,7 +112,7 @@ class Obs:
     @classmethod
     def noop(cls) -> "Obs":
         """All planes disabled — the seam is exercised, nothing is
-        recorded (the `bench_obs_overhead` comparison arm)."""
+        recorded."""
         return cls(metrics=False, tracing=False, profiling=False)
 
     @property

@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +50,7 @@ from jax import lax
 
 from repro.detection.batch import DetectionsBatch, _pad_dim
 from repro.detection.map_engine import Detections
-from repro.kernels.iou_matrix.ops import iou_matrix_batch, resolve_interpret
+from repro.kernels.iou_matrix.ops import iou_matrix_batch, resolve_path
 
 
 @dataclass(frozen=True)
@@ -150,7 +150,7 @@ def _init_state(n_streams: int, cfg: TrackerConfig):
     )
 
 
-def _step(state, frame, cfg: TrackerConfig, interpret: bool):
+def _step(state, frame, cfg: TrackerConfig, interpret: str):
     """One tracker update over all B streams — pure jnp/lax + the Pallas
     IoU kernel, scanned over T by :func:`track_clip`."""
     boxes, vel, conf, age, cls, ids, active, next_id = state
@@ -291,11 +291,11 @@ def track_clip(
     dets: "DetectionClip",
     config: Optional[TrackerConfig] = None,
     *,
-    interpret: Optional[bool] = None,
+    interpret: Union[None, bool, str] = None,
 ) -> TrackHistory:
     """Track a whole clip as ONE jitted ``lax.scan`` over T — per-frame IoU
-    through the Pallas kernel, association + state update as masked lax ops
-    over all streams at once."""
+    on the kernel path ``interpret`` resolves to, association + state
+    update as masked lax ops over all streams at once."""
     cfg = config or TrackerConfig()
     frames = (
         jnp.asarray(dets.boxes, jnp.float32),
@@ -304,7 +304,7 @@ def track_clip(
         jnp.asarray(dets.mask),
     )
     _, out = _scan_jit(
-        _init_state(dets.n_streams, cfg), frames, cfg, resolve_interpret(interpret)
+        _init_state(dets.n_streams, cfg), frames, cfg, resolve_path(interpret)
     )
     return TrackHistory(**{k: np.asarray(v) for k, v in out.items()})
 
@@ -483,11 +483,11 @@ class VideoTracker:
         n_streams: int = 1,
         config: Optional[TrackerConfig] = None,
         *,
-        interpret: Optional[bool] = None,
+        interpret: Union[None, bool, str] = None,
     ):
         self.config = config or TrackerConfig()
         self.n_streams = int(n_streams)
-        self._interpret = resolve_interpret(interpret)
+        self._interpret = resolve_path(interpret)
         self.reset()
 
     def reset(self) -> None:
@@ -559,7 +559,7 @@ def propagate_rematch_ref(
     """The naive alternative to tracked propagation: carry a stale result
     forward by re-matching it against EVERY intermediate frame's weak
     detections (per-frame Python greedy matching) — the O(T · N · M)
-    baseline ``bench_video_pipeline`` compares the tracker against."""
+    baseline for the tracker's ``propagate``."""
     boxes = np.asarray(edge_dets.boxes, np.float64).copy()
     classes = np.asarray(edge_dets.classes)
     scores = np.asarray(edge_dets.scores, np.float64).copy()

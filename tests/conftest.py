@@ -1,5 +1,5 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS here — tests run on the real
-1-device CPU view; only dryrun.py forces 512 host devices."""
+1-device CPU view."""
 import numpy as np
 import pytest
 

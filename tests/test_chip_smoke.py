@@ -17,9 +17,16 @@ ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
 sys.path.insert(0, ROOT)
 
 import chip_smoke as cs  # noqa: E402
+from repro.kernels import dispatch  # noqa: E402
 from repro.kernels.score_pipeline import ops as score_ops  # noqa: E402
 
-CPU = cs.Paths(pipeline="lax", kernel="reference", tracker_interpret=True, mosaic=False)
+CPU = cs.Paths(kernel="reference", mosaic=False)
+
+
+def _interpret_by_default(monkeypatch):
+    """``None`` resolves to the interpreter in the fused score route and the script's check."""
+    for module in (cs, score_ops):
+        monkeypatch.setattr(module, "resolve_path", lambda p=None: dispatch.resolve_path(p is None or p))
 
 
 @pytest.fixture(scope="module")
@@ -37,17 +44,9 @@ def test_main_refuses_cpu_backend(capsys):
 
 
 def test_decide_phase_runs_fused_kernel_interpreted(engine, monkeypatch):
-    auto = score_ops.resolve_pipeline_path
-    monkeypatch.setattr(
-        score_ops, "resolve_pipeline_path",
-        lambda path=None: "pallas_interpret" if path is None else auto(path),
-    )
-    paths = cs.Paths(
-        pipeline="pallas_interpret", kernel="reference",
-        tracker_interpret=True, mosaic=False,
-    )
-    out = cs.phase_decide(engine, 0, paths, n_images=200)
-    assert "path=pallas_interpret" in out["check"]
+    _interpret_by_default(monkeypatch)
+    out = cs.phase_decide(engine, 0, cs.Paths(kernel="interpret", mosaic=False), n_images=200)
+    assert "path=interpret max|interpret-reference|=" in out["check"]
 
 
 def test_decide_phase_refuses_unexpected_path(engine):
@@ -68,17 +67,9 @@ def test_session_phase_routes_agree_exactly(engine):
 
 
 def test_packed_phase_bit_identical_interpreted(monkeypatch):
-    auto = score_ops.resolve_pipeline_path
-    monkeypatch.setattr(
-        score_ops, "resolve_pipeline_path",
-        lambda path=None: "pallas_interpret" if path is None else auto(path),
-    )
-    paths = cs.Paths(
-        pipeline="pallas_interpret", kernel="reference",
-        tracker_interpret=True, mosaic=False,
-    )
-    out = cs.phase_packed(0, paths, rows=(1, 5), per_size=2)
-    assert "paths=pallas_interpret,lax" in out["check"]
+    _interpret_by_default(monkeypatch)
+    out = cs.phase_packed(0, cs.Paths(kernel="interpret", mosaic=False), rows=(1, 5), per_size=2)
+    assert "paths=interpret,reference" in out["check"]
     assert "max|packed-arrays|=0.000e+00" in out["check"]
     assert "bit_identical_blocks=8/8 packed_calls=8" in out["check"]
 

@@ -11,12 +11,8 @@ from repro.core import EstimatorConfig
 from repro.core.features import extract_features_batch
 from repro.detection.batch import DetectionsBatch
 from repro.detection.map_engine import Detections
-from repro.kernels.score_pipeline import (
-    PIPELINE_PATHS,
-    pipeline_params,
-    resolve_pipeline_path,
-    score_pipeline,
-)
+from repro.kernels.dispatch import KERNEL_PATHS, resolve_path
+from repro.kernels.score_pipeline import pipeline_params, score_pipeline
 from repro.kernels.score_pipeline.ops import (
     PACKED_SLOT_BYTES,
     pack_detections,
@@ -119,9 +115,9 @@ def test_pallas_kernel_matches_lax_path(fitted_engine, B, kmax, tile_b):
     db = make_batch(np.random.default_rng(B + kmax), B, kmax)
     params = eng.reward_model.pipeline_params()
     kw = dict(num_classes=NUM_CLASSES, top_k=TOP_K, image_size=1.0)
-    lax_out = np.asarray(score_pipeline(db, params, path="lax", **kw))
+    lax_out = np.asarray(score_pipeline(db, params, path="reference", **kw))
     pal_out = np.asarray(
-        score_pipeline(db, params, path="pallas_interpret", tile_b=tile_b, **kw)
+        score_pipeline(db, params, path="interpret", tile_b=tile_b, **kw)
     )
     np.testing.assert_allclose(pal_out, lax_out, atol=2e-6)
 
@@ -153,7 +149,7 @@ def _device_arrays(db):
             jnp.asarray(db.classes), jnp.asarray(db.mask))
 
 
-@pytest.mark.parametrize("path", ["lax", "pallas_interpret"])
+@pytest.mark.parametrize("path", ["reference", "interpret"])
 @pytest.mark.parametrize("B", [1, 37, 64])
 @pytest.mark.parametrize("K", [12, TOP_K, 40])
 def test_packed_route_bit_identical_to_device_arrays(fitted_engine, path, B, K):
@@ -235,15 +231,13 @@ def test_feature_dim_mismatch_raises(fitted_engine):
         score_pipeline(db, params, num_classes=NUM_CLASSES + 1, top_k=TOP_K)
 
 
-def test_resolve_pipeline_path():
+def test_resolve_path():
     import jax
 
-    assert resolve_pipeline_path("lax") == "lax"
-    auto = resolve_pipeline_path(None)
-    assert auto == ("lax" if jax.default_backend() == "cpu" else "pallas")
-    assert auto in PIPELINE_PATHS
+    assert [resolve_path(p) for p in KERNEL_PATHS + (True, False)] == [*KERNEL_PATHS, "interpret", "compiled"]
+    assert resolve_path(None) == ("reference" if jax.default_backend() == "cpu" else "compiled")
     with pytest.raises(ValueError):
-        resolve_pipeline_path("jnp")
+        resolve_path("lax")
 
 
 def test_pipeline_params_track_online_updates(fitted_engine):

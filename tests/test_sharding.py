@@ -1,5 +1,5 @@
 """Sharding rules + mesh context (1-device CPU view; the 512-device mesh
-is exercised by the dryrun CLI, not here).  The fleet-plane bit-identity
+is not built in these tests).  The fleet-plane bit-identity
 property runs in a subprocess with a forced multi-device host view — the
 in-process suite deliberately keeps the real 1-device view."""
 import os

@@ -95,7 +95,7 @@ def test_fused_score_kernel_compiles(one_chip, no_compile_cache, num_classes, to
     )
     compiled = _score_pipeline_pallas.lower(
         *args, num_classes=num_classes, top_k=top_k, tile_b=ENGINE_TILE_B,
-        interpret=False,
+        path="compiled",
     ).compile()
     _assert_kernel(compiled)
 
@@ -116,7 +116,7 @@ def test_packed_score_entry_compiles(one_chip, no_compile_cache, batch):
     )
     compiled = _score_packed.lower(
         *args, num_classes=num_classes, top_k=top_k, tile_b=ENGINE_TILE_B,
-        path="pallas",
+        path="compiled",
     ).compile()
     _assert_kernel(compiled)
 
